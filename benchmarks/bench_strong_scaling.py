@@ -4,7 +4,11 @@ Each shard count runs in a SUBPROCESS (XLA pins the device count at init),
 generating the same graph on nb = 1, 2, 4, 8 fake devices and timing the
 total + per-phase cost.  The paper's observation that small scales stop
 scaling early (scale-16 saturates at 2 nodes) reproduces as fixed per-shard
-overheads dominating."""
+overheads dominating.
+
+The children run with JAX_PLATFORMS=cpu: a CPU emulation of multi-node
+scaling that never contends for an accelerator, and whose rows name the
+platform."""
 
 from __future__ import annotations
 
@@ -51,6 +55,7 @@ res["redistribute"] = t(lambda: redistribute_sorted(cfg, mesh, ns, nd))
 owned = redistribute_sorted(cfg, mesh, ns, nd)
 res["csr"] = t(lambda: build_csr_sorted(cfg, mesh, owned))
 res["total"] = sum(res.values())
+res["platform"] = jax.devices()[0].platform
 print("RESULT " + json.dumps(res))
 """
 
@@ -59,7 +64,7 @@ def run(scales=(12, 14), shard_counts=(1, 2, 4, 8)):
     rows = []
     for s in scales:
         for nb in shard_counts:
-            env = dict(os.environ,
+            env = dict(os.environ, JAX_PLATFORMS="cpu",
                        XLA_FLAGS=f"--xla_force_host_platform_device_count={nb}",
                        PYTHONPATH="src")
             r = subprocess.run([sys.executable, "-c", _CHILD, str(s), str(nb)],
@@ -68,11 +73,12 @@ def run(scales=(12, 14), shard_counts=(1, 2, 4, 8)):
             assert r.returncode == 0, r.stderr[-2000:]
             line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][0]
             res = json.loads(line[len("RESULT "):])
+            platform = res.pop("platform")
             norm = 2.0 ** (s - 16)
-            rows.append({"scale": s, "nb": nb,
+            rows.append({"scale": s, "nb": nb, "platform": platform,
                          **{k: v / norm for k, v in res.items()}})
     print_table("Fig.3/4: strong scaling, per-phase time / 2^(s-16) [s]",
-                rows, ["scale", "nb", "total", "shuffle", "edge_gen",
+                rows, ["scale", "nb", "platform", "total", "shuffle", "edge_gen",
                        "relabel", "redistribute", "csr"])
     save_json("strong_scaling", rows)
     return rows

@@ -4,7 +4,11 @@ per-shard work is constant.  The paper finds these two phases scale
 SUB-linearly: relabel because every shard scans the whole permutation
 vector, redistribute because R-MAT degree skew concentrates edges on a few
 owners.  Both effects reproduce here (the skew one shows up as rising
-capacity-driven padding)."""
+capacity-driven padding).
+
+Each point runs in a child process on nb virtual CPU devices
+(JAX_PLATFORMS=cpu): a CPU emulation of the multi-node schedule that never
+contends for an accelerator, and whose rows name the platform."""
 
 from __future__ import annotations
 
@@ -44,6 +48,7 @@ res = {}
 res["relabel"] = t(lambda: relabel_ring(cfg, mesh, src, dst, pv))
 ns, nd = relabel_ring(cfg, mesh, src, dst, pv)
 res["redistribute"] = t(lambda: redistribute_sorted(cfg, mesh, ns, nd))
+res["platform"] = jax.devices()[0].platform
 print("RESULT " + json.dumps(res))
 """
 
@@ -52,7 +57,7 @@ def run(base_scale=10, steps=4):
     rows = []
     for i in range(steps):
         s, nb = base_scale + i, 1 << i
-        env = dict(os.environ,
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
                    XLA_FLAGS=f"--xla_force_host_platform_device_count={nb}",
                    PYTHONPATH="src")
         r = subprocess.run([sys.executable, "-c", _CHILD, str(s), str(nb)],
@@ -62,7 +67,7 @@ def run(base_scale=10, steps=4):
         res = json.loads(line[len("RESULT "):])
         rows.append({"(s, nb)": f"({s},{nb})", **res})
     print_table("Fig.5: weak scaling of relabel/redistribute [s]",
-                rows, ["(s, nb)", "relabel", "redistribute"])
+                rows, ["(s, nb)", "platform", "relabel", "redistribute"])
     save_json("weak_scaling", rows)
     return rows
 

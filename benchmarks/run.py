@@ -61,6 +61,9 @@ def main():
     args = ap.parse_args()
 
     from repro.core.trace import GLOBAL as METRICS, run_metadata
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from . import (bench_csr_variants, bench_external_shuffle,
                    bench_external_walks, bench_hash_vs_sort, bench_jobqueue,

@@ -15,6 +15,7 @@ from repro.core import validate as V
 from repro.core.external import StreamingGenerator
 from repro.core.pipeline import generate, generate_baseline_hash
 from repro.core.types import GraphConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -23,7 +24,11 @@ def main():
     ap.add_argument("--edge-factor", type=int, default=16)
     args = ap.parse_args()
 
-    nb = len(jax.devices())
+    use_compile_cache()
+    devices = jax.devices()
+    print(f"device: platform={devices[0].platform} kind={devices[0].device_kind} "
+          f"count={len(devices)}")
+    nb = len(devices)
     cfg = GraphConfig(scale=args.scale, edge_factor=args.edge_factor,
                       nb=nb, capacity_factor=4.0)
     print(f"scale={args.scale} -> {cfg.n} vertices, {cfg.m} edges, "

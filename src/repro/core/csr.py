@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..distributed.collectives import shard_map
 from .redistribute import OwnedEdges
 from .types import GraphConfig
 
@@ -80,7 +79,7 @@ def build_csr_scatter(cfg: GraphConfig, mesh: Mesh, owned: OwnedEdges, axis: str
         adjv = jnp.where(jnp.arange(order.shape[0]) < cnt, d[order], 0)
         return offv, adjv, cnt[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis)),
@@ -109,7 +108,7 @@ def build_csr_sorted(cfg: GraphConfig, mesh: Mesh, owned: OwnedEdges, axis: str 
         adjv = jnp.where(jnp.arange(d.shape[0]) < cnt, d, 0)
         return offv, adjv, cnt[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis)),

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..distributed.collectives import flat_mesh, shard_map
+from ..distributed.collectives import flat_mesh
 from .csr import CSRShards, build_csr_scatter, build_csr_sorted
 from .redistribute import OwnedEdges, redistribute, redistribute_sorted
 from .relabel import relabel_alltoall, relabel_recompute, relabel_ring
@@ -53,7 +53,7 @@ def generate_edges(cfg: GraphConfig, mesh: Mesh, axis: str = "shards"):
         start = (bid * eps).astype(jnp.uint32)
         return rmat_edge_block(cfg, start, eps)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh, in_specs=(P(axis),), out_specs=(P(axis), P(axis))
     )
     return fn(jnp.zeros((mesh.shape[axis],), jnp.int32))
@@ -96,6 +96,9 @@ def generate(
         new_src, new_dst, dropped_rel = relabel_alltoall(cfg, mesh, src, dst, pv, axis)
     else:
         raise ValueError(cfg.relabel_variant)
+    # The raw edges are not part of the result: free them before the
+    # redistribute, the phase with the largest footprint.
+    del src, dst
 
     # 4+5. redistribute + CSR
     if cfg.csr_variant == "sorted":

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..distributed.collectives import capacity_all_to_all, merge_sorted_runs, shard_map
+from ..distributed.collectives import capacity_all_to_all, merge_sorted_runs
 from .types import GraphConfig
 
 
@@ -59,7 +59,10 @@ class OwnedEdges(NamedTuple):
 
 
 def _default_capacity(cfg: GraphConfig, nb: int) -> int:
-    return int(cfg.capacity_factor * cfg.edges_per_shard / max(nb, 1)) + 8
+    # Rounded up to whole (8, 128) int32 tiles: an unaligned capacity costs
+    # the TPU compile of the exchange time and temporary memory.
+    cap = int(cfg.capacity_factor * cfg.edges_per_shard / max(nb, 1)) + 8
+    return -(-cap // 1024) * 1024
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "axis", "capacity"))
@@ -81,7 +84,7 @@ def redistribute(
         ex = capacity_all_to_all(pair, src_l // B, axis=axis, capacity=cap)
         return ex.data[..., 0], ex.data[..., 1], ex.valid, ex.dropped
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P()),
@@ -131,7 +134,7 @@ def redistribute_sorted(
             ex.dropped,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P()),

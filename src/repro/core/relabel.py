@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..distributed.collectives import capacity_all_to_all, return_all_to_all, ring_shift, shard_map
+from ..distributed.collectives import capacity_all_to_all, return_all_to_all, ring_shift
 from .types import GraphConfig
 
 
@@ -104,7 +104,7 @@ def relabel_ring(
         new_src = _relabel_field_ring(src_l, pv_l, bid=bid, nb=nb, B=B, axis=axis)
         return new_src, new_dst
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
@@ -168,7 +168,7 @@ def relabel_alltoall(
         new_src, new_dst = jnp.split(back, 2)
         return new_src, new_dst, ex.dropped
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),

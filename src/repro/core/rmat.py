@@ -25,12 +25,16 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .types import GraphConfig, quadrant_thresholds
 
 # splitmix32-style avalanche constants (Stafford / murmur3 finalizer family).
-_M1 = jnp.uint32(0x7FEB352D)
-_M2 = jnp.uint32(0x846CA68B)
+# numpy scalars, not jnp: a jnp constant here would start a JAX backend at
+# import, and the disk tier's worker processes import this package without
+# doing any JAX work (one process per chip).
+_M1 = np.uint32(0x7FEB352D)
+_M2 = np.uint32(0x846CA68B)
 _GOLDEN = 0x9E3779B9
 
 

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..distributed.collectives import shard_map
 from .hostgen import (
     FEISTEL_ROUNDS,
     feistel_round_key_np,
@@ -91,7 +90,7 @@ def distributed_shuffle(cfg: GraphConfig, mesh: Mesh, axis: str = "shards") -> j
         sbuf = lax.fori_loop(0, rounds, _shuffle_rounds_body(nb, axis, cfg.seed), sbuf)
         return sbuf
 
-    shard_fn = shard_map(
+    shard_fn = jax.shard_map(
         per_shard, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis)
     )
     dummy = jnp.zeros((nb,), jnp.int32)  # carries the axis, no data

@@ -72,7 +72,7 @@ from ..core.phases import (
 )
 from ..core.transport import make_transport
 from ..core.types import GraphConfig, owner_of
-from ..distributed.collectives import capacity_all_to_all, pvary, shard_map
+from ..distributed.collectives import capacity_all_to_all
 
 
 def _mix32_jnp(x: jnp.ndarray) -> jnp.ndarray:
@@ -200,7 +200,7 @@ def distributed_walks(
         pos, wid = pad_to(pos), pad_to(wid, -1)
         # alive starts axis-invariant but becomes axis-varying through the
         # exchange; mark it varying so the scan carry types match
-        alive = pvary(pad_to(alive), (axis,))
+        alive = lax.pcast(pad_to(alive), axis, to="varying")
         hist = jnp.zeros((cap, length + 1), vdt).at[:, 0].set(pos)
 
         def step(carry, t):
@@ -236,7 +236,7 @@ def distributed_walks(
         # per-step totals; sum over steps, report one copy per shard.
         return hist, alive, wid, jnp.sum(dropped)[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P(axis)),

@@ -31,15 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 
 # ---------------------------------------------------------------------------
-# Version compat
-# ---------------------------------------------------------------------------
-
-# The version-floor shims (shard_map / axis_size / pvary for jax 0.4.37) live
-# in distributed/compat.py; re-exported here because historically every module
-# imported them from this file — both import paths stay valid.
-from .compat import axis_size, pvary, shard_map  # noqa: F401
-
-# ---------------------------------------------------------------------------
 # Mesh helpers
 # ---------------------------------------------------------------------------
 
@@ -100,11 +91,14 @@ def bucket_by_destination(data: jnp.ndarray, dest: jnp.ndarray, k: int, capacity
     slot = jnp.where(keep, dest * capacity + rank, k * capacity)  # overflow -> scratch slot
     flat_shape = (k * capacity + 1,) + data.shape[1:]
     flat = jnp.zeros(flat_shape, data.dtype).at[slot].set(data, mode="drop")
-    occupied = jnp.zeros((k * capacity + 1,), jnp.bool_).at[slot].set(True, mode="drop")
+    # Occupancy is marked in int32, not bool: a large bool scatter (and the
+    # bool all_to_all after it) takes the TPU compiler minutes and several
+    # times the host memory of the int32 one.
+    occupied = jnp.zeros((k * capacity + 1,), jnp.int32).at[slot].set(1, mode="drop")
     dropped = jnp.sum((rank >= capacity) & (dest < k)).astype(jnp.int32)
     return Buckets(
         data=flat[:-1].reshape((k, capacity) + data.shape[1:]),
-        valid=occupied[:-1].reshape(k, capacity),
+        valid=occupied[:-1].reshape(k, capacity) == 1,
         position=slot,
         dropped=dropped,
     )
@@ -149,10 +143,12 @@ def capacity_all_to_all(
     records, `dest` [N] destination shard ids in [0, k).  Rows with
     valid=False are discarded without consuming capacity.
     """
-    k = axis_size(axis)
+    k = lax.axis_size(axis)
     b = bucket_by_destination(data, dest, k, capacity, valid=valid)
     recv = lax.all_to_all(b.data, axis, split_axis=0, concat_axis=0, tiled=False)
-    recv_valid = lax.all_to_all(b.valid, axis, split_axis=0, concat_axis=0, tiled=False)
+    # the mask crosses as int32 for the reason bucket_by_destination marks it so
+    recv_valid = lax.all_to_all(b.valid.astype(jnp.int32), axis, split_axis=0,
+                                concat_axis=0, tiled=False) == 1
     dropped = lax.psum(b.dropped, axis)
     return ExchangeResult(recv, recv_valid, b.position, dropped)
 
@@ -188,7 +184,7 @@ def ring_shift(x: jnp.ndarray, axis: str, shift: int = 1) -> jnp.ndarray:
     past every shard in nb rounds — sequential access on the ICI, the exact
     analogue of the paper turning random disk I/O into sequential scans.
     """
-    k = axis_size(axis)
+    k = lax.axis_size(axis)
     perm = [(i, (i - shift) % k) for i in range(k)]  # (source, destination)
     return lax.ppermute(x, axis, perm)
 
@@ -210,12 +206,16 @@ def merge_two_sorted(a: jnp.ndarray, b: jnp.ndarray, a_payload=None, b_payload=N
     na, nb_ = a.shape[0], b.shape[0]
     pos_a = jnp.arange(na, dtype=jnp.int32) + jnp.searchsorted(b, a, side="left").astype(jnp.int32)
     pos_b = jnp.arange(nb_, dtype=jnp.int32) + jnp.searchsorted(a, b, side="right").astype(jnp.int32)
+    # pos_a and pos_b are each strictly increasing (a sorted index plus a
+    # monotone rank).  Saying so lets the TPU compiler emit a plain scatter,
+    # which compiles faster and needs less temporary memory.
+    put = dict(indices_are_sorted=True, unique_indices=True)
     out = jnp.zeros((na + nb_,), a.dtype)
-    out = out.at[pos_a].set(a).at[pos_b].set(b)
+    out = out.at[pos_a].set(a, **put).at[pos_b].set(b, **put)
     if a_payload is None:
         return out
     pay = jnp.zeros((na + nb_,) + a_payload.shape[1:], a_payload.dtype)
-    pay = pay.at[pos_a].set(a_payload).at[pos_b].set(b_payload)
+    pay = pay.at[pos_a].set(a_payload, **put).at[pos_b].set(b_payload, **put)
     return out, pay
 
 
@@ -225,26 +225,23 @@ def merge_sorted_runs(keys: jnp.ndarray, payload: Optional[jnp.ndarray] = None):
     O(m log k) work with sequential access — cheaper than re-sorting
     (O(m log m)) and faithful to the paper's sorted-merge redistribute.
     k must be a power of two (mesh axis sizes are).
+
+    Runs stay separate arrays between rounds: stacking a round's outputs
+    and slicing the next round's inputs back out of the stack makes the
+    TPU compile of a 4-way merge of 2^24-long runs take minutes.
     """
-    k, run = keys.shape
+    k = keys.shape[0]
     assert (k & (k - 1)) == 0, f"k={k} must be a power of two"
-    flatp = payload
-    while k > 1:
-        halves = keys.reshape(k // 2, 2, -1)
-        if flatp is not None:
-            ph = flatp.reshape((k // 2, 2, halves.shape[-1]) + flatp.shape[2:])
-        merged_k, merged_p = [], []
-        for i in range(k // 2):
-            if flatp is None:
-                merged_k.append(merge_two_sorted(halves[i, 0], halves[i, 1]))
-            else:
-                mk, mp = merge_two_sorted(halves[i, 0], halves[i, 1], ph[i, 0], ph[i, 1])
-                merged_k.append(mk)
-                merged_p.append(mp)
-        keys = jnp.stack(merged_k)
-        if flatp is not None:
-            flatp = jnp.stack(merged_p)
-        k //= 2
+    runs = [keys[i] for i in range(k)]
+    pays = [None if payload is None else payload[i] for i in range(k)]
+    while len(runs) > 1:
+        if payload is None:
+            runs = [merge_two_sorted(runs[i], runs[i + 1])
+                    for i in range(0, len(runs), 2)]
+        else:
+            merged = [merge_two_sorted(runs[i], runs[i + 1], pays[i], pays[i + 1])
+                      for i in range(0, len(runs), 2)]
+            runs, pays = [m[0] for m in merged], [m[1] for m in merged]
     if payload is None:
-        return keys[0]
-    return keys[0], flatp[0]
+        return runs[0]
+    return runs[0], pays[0]

@@ -93,7 +93,8 @@ def flash_attention_pallas(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape

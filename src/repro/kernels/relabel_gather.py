@@ -42,7 +42,7 @@ def _relabel_kernel(base_ref, keys_ref, pv_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def relabel_gather_pallas(
-    keys: jnp.ndarray, pv_chunk: jnp.ndarray, base: jnp.ndarray, interpret: bool = True
+    keys: jnp.ndarray, pv_chunk: jnp.ndarray, base: jnp.ndarray, *, interpret: bool
 ) -> jnp.ndarray:
     """Relabel keys in [base, base+B) through pv_chunk; others pass through.
 

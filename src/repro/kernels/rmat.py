@@ -66,7 +66,7 @@ def _rmat_kernel(o_src_ref, o_dst_ref, *, seed: int, scale: int, thresholds, sta
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "start", "count", "interpret"))
-def rmat_edges_pallas(cfg: GraphConfig, start: int, count: int, interpret: bool = True):
+def rmat_edges_pallas(cfg: GraphConfig, start: int, count: int, *, interpret: bool):
     """Generate `count` edges with global ids [start, start+count).
 
     count must be a multiple of TILE (ops.py pads otherwise).
@@ -117,8 +117,8 @@ def _feistel_kernel(x_ref, o_ref, *, key: int, nbits: int, rounds: int):
 
 @functools.partial(jax.jit, static_argnames=("key", "nbits", "rounds", "interpret"))
 def feistel_perm_pallas(x: jnp.ndarray, key: int, nbits: int,
-                        rounds: int = FEISTEL_ROUNDS,
-                        interpret: bool = True) -> jnp.ndarray:
+                        rounds: int = FEISTEL_ROUNDS, *,
+                        interpret: bool) -> jnp.ndarray:
     """Permute int32 ids through the keyed Feistel bijection on
     [0, 2**nbits), as (BLOCK_ROWS, 128) VMEM tiles.
 

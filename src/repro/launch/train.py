@@ -36,6 +36,7 @@ from ..data import ExternalWalkLoader, LoaderConfig, WalkLoader
 from ..distributed.collectives import flat_mesh
 from ..models.registry import get_model
 from ..train import OptimConfig, checkpoint, init_state, make_train_step
+from .compile_cache import use_compile_cache
 
 
 def main(argv=None):
@@ -64,6 +65,7 @@ def main(argv=None):
                          "manifest (e.g. a launch/cluster.py run's output) "
                          "instead of generating; implies --data external")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.corpus_manifest:
         args.data = "external"
 

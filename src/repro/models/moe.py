@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.collectives import capacity_all_to_all, return_all_to_all, shard_map
+from ..distributed.collectives import capacity_all_to_all, return_all_to_all
 from .nn import DistContext, ParamFactory, shard
 
 
@@ -296,7 +296,7 @@ def moe_ffn(p, cfg, x: jnp.ndarray, dist: Optional[DistContext]) -> Tuple[jnp.nd
             "w_down": _expert_spec(p["w_down"], axis),
         }
         routed_p = {k: p[k] for k in ("router", "w_gate", "w_up", "w_down")}
-        fn = shard_map(
+        fn = jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(specs_p, x_spec),

@@ -24,8 +24,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..distributed.collectives import axis_size
-
 
 @dataclasses.dataclass(frozen=True)
 class CompressionConfig:
@@ -122,7 +120,7 @@ def podwise_psum_int8(grads, axis: str = "pod"):
         scale = jnp.where(amax > 0, amax / 127.0, 1.0)
         q = jnp.clip(jnp.round(g / scale), -127, 127).astype(jnp.int32)
         qsum = jax.lax.psum(q, axis)
-        npods = axis_size(axis)
+        npods = jax.lax.axis_size(axis)
         return qsum.astype(jnp.float32) * scale / npods
 
     return jax.tree.map(leaf, grads)

@@ -175,7 +175,7 @@ def test_podwise_int8_psum():
     out = run_py("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed.collectives import shard_map
+from jax import shard_map
 from repro.train.compression import podwise_psum_int8
 
 mesh = Mesh(np.asarray(jax.devices()).reshape(8), ('pod',))
@@ -193,3 +193,25 @@ for i in range(8):
 print("OKPSUM")
 """)
     assert "OKPSUM" in out
+
+
+def test_shard_map_axis_size_pcast_compose():
+    """jax.shard_map, lax.axis_size and lax.pcast used directly (no compat
+    layer) compose inside a one-device shard_map: axis_size folds to the
+    mesh axis length, and pcast marks an axis-invariant value as varying
+    (what distributed_walks does to its scan carry)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("i",))
+
+    def body(x):
+        ones = lax.pcast(jnp.ones(x.shape, x.dtype), "i", to="varying")
+        return x + ones * lax.axis_size("i")
+
+    y = jax.shard_map(body, mesh=mesh, in_specs=P("i"), out_specs=P("i"))(
+        jnp.zeros(4, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(y), np.ones(4, np.int32))
