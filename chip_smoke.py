@@ -18,8 +18,8 @@ Default mode (one chip, nb=1):
 --four-chip runs only what exists across chips (all_to_all, ppermute,
 capacity_all_to_all): the full validate.* checks at VALIDATION_SCALE on
 nb=4, recompute's nb=4 graph against its nb=1 graph, and Graph500 "toy"
-(scale 26, paper shuffle) once, with the same device-side checks and walks
-but without the per-phase steady rerun.
+(scale 26, paper shuffle) once, with the same device-side checks and walks.
+Per-phase device times are the benchmark's (benchmarks/chip/).
 
 Every check raises on failure.  The seconds, rates and bytes printed are
 smoke readings of one run, not benchmark numbers.  The last line of
@@ -41,13 +41,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 import numpy as np  # noqa: E402
 
 from repro.core import validate as V  # noqa: E402
-from repro.core.csr import build_csr_sorted, csr_to_host  # noqa: E402
+from repro.core.csr import csr_to_host  # noqa: E402
 from repro.core.external import StreamingGenerator  # noqa: E402
 from repro.core.hostgen import graph_perm_np  # noqa: E402
 from repro.core.pipeline import generate, generate_edges  # noqa: E402
-from repro.core.redistribute import redistribute_sorted  # noqa: E402
-from repro.core.relabel import relabel_ring  # noqa: E402
-from repro.core.shuffle import distributed_shuffle  # noqa: E402
 from repro.core.types import GraphConfig  # noqa: E402
 from repro.data.walks import distributed_walks, host_walks, start_vertex  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
@@ -184,20 +181,6 @@ def validate_one_chip(mesh: Mesh) -> None:
     report_compiles("validate")
 
 
-def phase_seconds(cfg: GraphConfig, mesh: Mesh) -> dict:
-    """Steady seconds of each phase of generate()'s paper path, in its
-    order.  The calls match generate()'s, so they reuse its compiled
-    programs: any compile here is reported as one."""
-    ax, t = "shards", {}
-    pv, t["distributed_shuffle"] = timed(distributed_shuffle, cfg, mesh, ax)
-    (src, dst), t["generate_edges"] = timed(generate_edges, cfg, mesh, ax)
-    (new_src, new_dst), t["relabel_ring"] = timed(relabel_ring, cfg, mesh, src, dst, pv, ax)
-    del src, dst
-    owned, t["redistribute_sorted"] = timed(redistribute_sorted, cfg, mesh, new_src, new_dst, ax)
-    _, t["build_csr_sorted"] = timed(build_csr_sorted, cfg, mesh, owned, ax)
-    return t
-
-
 def real_scale(cfg: GraphConfig, mesh: Mesh, walk_capacity: float,
                variants=("paper", "recompute")) -> None:
     say(f"[real] scale {cfg.scale}: n={cfg.n} m={cfg.m}, nb={cfg.nb}, "
@@ -216,15 +199,6 @@ def real_scale(cfg: GraphConfig, mesh: Mesh, walk_capacity: float,
         # Drop the result before the next generate(): two graphs of this
         # size do not fit the chip together.
         del res
-
-
-def report_steady(cfg: GraphConfig, mesh: Mesh) -> None:
-    steady = phase_seconds(cfg, mesh)
-    for phase, secs in steady.items():
-        say(f"  steady_s[paper] {phase}: {secs!r}")
-    total = sum(steady.values())
-    say(f"  steady_s[paper] total: {total!r}, edges/s {cfg.m / total!r}")
-    report_compiles("steady phases")
 
 
 def report_peak(mesh: Mesh) -> None:
@@ -295,8 +269,8 @@ def main(argv=None) -> int:
         mesh = mesh_of(devices[:4])
         validate_four_chip(mesh, mesh_of(devices[:1]))
         # recompute's cross-chip graph is checked at VALIDATION_SCALE; at
-        # full size only the paper path and no steady rerun: one scale-26
-        # generate() takes about ten minutes on four v5e chips.
+        # full size only the paper path: one scale-26 generate() takes
+        # about ten minutes on four v5e chips.
         real_scale(GraphConfig(scale=FOUR_CHIP_SCALE, nb=4, capacity_factor=1.1),
                    mesh, walk_capacity=4.0, variants=("paper",))
     else:
@@ -306,7 +280,6 @@ def main(argv=None) -> int:
         # so the exchange is lossless; 2.0 does not fit the chip at scale 24.
         cfg = GraphConfig(scale=REAL_SCALE, nb=1, capacity_factor=1.0)
         real_scale(cfg, mesh, walk_capacity=1.0)
-        report_steady(cfg, mesh)
     report_peak(mesh)
 
     print(json.dumps({"ok": True, "device": device}), flush=True)

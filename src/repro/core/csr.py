@@ -63,20 +63,23 @@ def build_csr_scatter(cfg: GraphConfig, mesh: Mesh, owned: OwnedEdges, axis: str
     B = cfg.bucket_size
 
     def per_shard(src, dst, valid):
-        bid = lax.axis_index(axis)
-        base = bid * B
-        s, d, v = src.reshape(-1), dst.reshape(-1), valid.reshape(-1)
-        degv = _degrees(s, v, base, B)
-        offv = _offsets(degv)
+        with jax.named_scope("place"):
+            bid = lax.axis_index(axis)
+            base = bid * B
+            s, d, v = src.reshape(-1), dst.reshape(-1), valid.reshape(-1)
+            degv = _degrees(s, v, base, B)
+            offv = _offsets(degv)
         # adjacency: position = offv[row] + within-row rank.  After a stable
         # sort by row key (invalid -> B, sinks to the end) the sorted order
         # IS that placement: edge i of the sorted stream lands at adjv[i].
         # This sort is exactly the cost the paper's Fig. 2 charges to the
         # unordered CSR variant; §III-B7 (build_csr_sorted) avoids it.
-        rows = jnp.where(v, jnp.clip(s - base, 0, B - 1), B)
-        order = jnp.argsort(rows, stable=True)              # the hidden sort
-        cnt = jnp.sum(v.astype(jnp.int32))
-        adjv = jnp.where(jnp.arange(order.shape[0]) < cnt, d[order], 0)
+        with jax.named_scope("sort"):
+            rows = jnp.where(v, jnp.clip(s - base, 0, B - 1), B)
+            order = jnp.argsort(rows, stable=True)          # the hidden sort
+        with jax.named_scope("permute"):
+            cnt = jnp.sum(v.astype(jnp.int32))
+            adjv = jnp.where(jnp.arange(order.shape[0]) < cnt, d[order], 0)
         return offv, adjv, cnt[None]
 
     fn = jax.shard_map(
@@ -84,7 +87,8 @@ def build_csr_scatter(cfg: GraphConfig, mesh: Mesh, owned: OwnedEdges, axis: str
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis)),
     )
-    offv, adjv, cnt = fn(owned.src, owned.dst, owned.valid)
+    with jax.named_scope("csr"):
+        offv, adjv, cnt = fn(owned.src, owned.dst, owned.valid)
     return CSRShards(offv, adjv, cnt)
 
 
@@ -96,16 +100,20 @@ def build_csr_sorted(cfg: GraphConfig, mesh: Mesh, owned: OwnedEdges, axis: str 
     B = cfg.bucket_size
 
     def per_shard(src, dst, valid):
-        bid = lax.axis_index(axis)
-        base = bid * B
+        with jax.named_scope("search"):
+            bid = lax.axis_index(axis)
+            base = bid * B
         s, d, v = src.reshape(-1), dst.reshape(-1), valid.reshape(-1)
-        cnt = jnp.sum(v.astype(jnp.int32))
-        # rows sorted ascending over the valid prefix (invalid sorted to end
-        # by redistribute_sorted's sentinel keys).
-        keyed = jnp.where(v, s - base, B)
-        offv_full = jnp.searchsorted(keyed, jnp.arange(B + 1, dtype=keyed.dtype), side="left")
-        offv = offv_full.astype(jnp.int32)
-        adjv = jnp.where(jnp.arange(d.shape[0]) < cnt, d, 0)
+        with jax.named_scope("place"):
+            cnt = jnp.sum(v.astype(jnp.int32))
+        with jax.named_scope("search"):
+            # rows sorted ascending over the valid prefix (invalid sorted to
+            # end by redistribute_sorted's sentinel keys).
+            keyed = jnp.where(v, s - base, B)
+            offv_full = jnp.searchsorted(keyed, jnp.arange(B + 1, dtype=keyed.dtype), side="left")
+            offv = offv_full.astype(jnp.int32)
+        with jax.named_scope("place"):
+            adjv = jnp.where(jnp.arange(d.shape[0]) < cnt, d, 0)
         return offv, adjv, cnt[None]
 
     fn = jax.shard_map(
@@ -113,7 +121,8 @@ def build_csr_sorted(cfg: GraphConfig, mesh: Mesh, owned: OwnedEdges, axis: str 
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis)),
     )
-    offv, adjv, cnt = fn(owned.src, owned.dst, owned.valid)
+    with jax.named_scope("csr"):
+        offv, adjv, cnt = fn(owned.src, owned.dst, owned.valid)
     return CSRShards(offv, adjv, cnt)
 
 

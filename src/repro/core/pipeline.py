@@ -15,7 +15,7 @@ streaming, the paper's SSD tier) is core/external.py's StreamingGenerator.
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +27,7 @@ from .redistribute import OwnedEdges, redistribute, redistribute_sorted
 from .relabel import relabel_alltoall, relabel_recompute, relabel_ring
 from .rmat import rmat_edge_block
 from .shuffle import distributed_shuffle, shuffle_argsort, shuffle_recompute
+from .trace import phase_span
 from .types import GraphConfig
 
 
@@ -56,7 +57,8 @@ def generate_edges(cfg: GraphConfig, mesh: Mesh, axis: str = "shards"):
     fn = jax.shard_map(
         per_shard, mesh=mesh, in_specs=(P(axis),), out_specs=(P(axis), P(axis))
     )
-    return fn(jnp.zeros((mesh.shape[axis],), jnp.int32))
+    with jax.named_scope("edges"), jax.named_scope("rng"):
+        return fn(jnp.zeros((mesh.shape[axis],), jnp.int32))
 
 
 def generate(
@@ -66,49 +68,84 @@ def generate(
     shuffle_variant: str = "paper",        # "paper" | "argsort" | "recompute"
 ) -> GraphResult:
     """Run the full pipeline.  Returns device arrays (sharded over `axis`)."""
+    return _phases(cfg, mesh, axis, shuffle_variant, lambda fn, *args: fn(*args))
+
+
+def compile_phases(
+    cfg: GraphConfig,
+    mesh: Optional[Mesh] = None,
+    axis: str = "shards",
+    shuffle_variant: str = "paper",
+) -> Dict[str, jax.stages.Compiled]:
+    """Compile every phase program that generate() runs, for the same
+    arguments, and run none: {function name: compiled}.  Each program's
+    optimised HLO (`as_text()`) gives every operation's scopes."""
+    compiled: Dict[str, jax.stages.Compiled] = {}
+
+    def compile_only(fn, *args):
+        compiled[fn.__name__] = fn.lower(*args).compile()
+        return compiled[fn.__name__].out_info
+
+    _phases(cfg, mesh, axis, shuffle_variant, compile_only)
+    return compiled
+
+
+def _phases(cfg, mesh, axis, shuffle_variant, call) -> GraphResult:
+    """The phases in the paper's order, each phase program run as
+    `call(fn, *args)`."""
     mesh = mesh if mesh is not None else flat_mesh(cfg.nb, axis)
     assert mesh.shape[axis] == cfg.nb
 
+    # Each phase's dispatch lies in a host span named after it; the graph's
+    # seed ties the spans of one graph together.
+    def span(phase):
+        return phase_span(f"gen.{phase}", seed=cfg.seed)
+
     # 1. permutation phase
-    if shuffle_variant == "paper":
-        pv = distributed_shuffle(cfg, mesh, axis)
-    elif shuffle_variant == "argsort":
-        pv = shuffle_argsort(cfg, mesh, axis)
-    elif shuffle_variant == "recompute":
-        # Communication-free: the permutation is the keyed Feistel family.
-        # pv is materialized only because GraphResult exposes it — the
-        # relabel below recomputes labels directly and never reads it.
-        pv = shuffle_recompute(cfg, mesh, axis)
-    else:
-        raise ValueError(shuffle_variant)
+    with span("shuffle"):
+        if shuffle_variant == "paper":
+            pv = call(distributed_shuffle, cfg, mesh, axis)
+        elif shuffle_variant == "argsort":
+            pv = call(shuffle_argsort, cfg, mesh, axis)
+        elif shuffle_variant == "recompute":
+            # Communication-free: the permutation is the keyed Feistel family.
+            # pv is materialized only because GraphResult exposes it — the
+            # relabel below recomputes labels directly and never reads it.
+            pv = call(shuffle_recompute, cfg, mesh, axis)
+        else:
+            raise ValueError(shuffle_variant)
 
     # 2. edge generation phase
-    src, dst = generate_edges(cfg, mesh, axis)
+    with span("edges"):
+        src, dst = call(generate_edges, cfg, mesh, axis)
 
     # 3. relabeling phase
-    dropped_rel = jnp.zeros((), jnp.int32)
-    if shuffle_variant == "recompute":
-        # Zero collectives: both endpoints relabel as hash evaluations.
-        new_src, new_dst = relabel_recompute(cfg, mesh, src, dst, axis)
-    elif cfg.relabel_variant == "ring":
-        new_src, new_dst = relabel_ring(cfg, mesh, src, dst, pv, axis)
-    elif cfg.relabel_variant == "alltoall":
-        new_src, new_dst, dropped_rel = relabel_alltoall(cfg, mesh, src, dst, pv, axis)
-    else:
-        raise ValueError(cfg.relabel_variant)
+    with span("relabel"):
+        dropped_rel = jnp.zeros((), jnp.int32)
+        if shuffle_variant == "recompute":
+            # Zero collectives: both endpoints relabel as hash evaluations.
+            new_src, new_dst = call(relabel_recompute, cfg, mesh, src, dst, axis)
+        elif cfg.relabel_variant == "ring":
+            new_src, new_dst = call(relabel_ring, cfg, mesh, src, dst, pv, axis)
+        elif cfg.relabel_variant == "alltoall":
+            new_src, new_dst, dropped_rel = call(relabel_alltoall, cfg, mesh, src, dst, pv, axis)
+        else:
+            raise ValueError(cfg.relabel_variant)
     # The raw edges are not part of the result: free them before the
     # redistribute, the phase with the largest footprint.
     del src, dst
 
     # 4+5. redistribute + CSR
     if cfg.csr_variant == "sorted":
-        owned = redistribute_sorted(cfg, mesh, new_src, new_dst, axis)
-        csr = build_csr_sorted(cfg, mesh, owned, axis)
+        redistribute_fn, build_csr = redistribute_sorted, build_csr_sorted
     elif cfg.csr_variant == "scatter":
-        owned = redistribute(cfg, mesh, new_src, new_dst, axis)
-        csr = build_csr_scatter(cfg, mesh, owned, axis)
+        redistribute_fn, build_csr = redistribute, build_csr_scatter
     else:
         raise ValueError(cfg.csr_variant)
+    with span("redistribute"):
+        owned = call(redistribute_fn, cfg, mesh, new_src, new_dst, axis)
+    with span("csr"):
+        csr = call(build_csr, cfg, mesh, owned, axis)
 
     return GraphResult(pv, new_src, new_dst, owned, csr, dropped_rel, owned.dropped)
 

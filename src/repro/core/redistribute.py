@@ -80,16 +80,20 @@ def redistribute(
     cap = capacity or _default_capacity(cfg, nb)
 
     def per_shard(src_l, dst_l):
-        pair = jnp.stack([src_l, dst_l], axis=-1)          # [N, 2]
-        ex = capacity_all_to_all(pair, src_l // B, axis=axis, capacity=cap)
-        return ex.data[..., 0], ex.data[..., 1], ex.valid, ex.dropped
+        with jax.named_scope("place"):
+            pair = jnp.stack([src_l, dst_l], axis=-1)      # [N, 2]
+            owner = src_l // B
+        ex = capacity_all_to_all(pair, owner, axis=axis, capacity=cap)
+        with jax.named_scope("place"):
+            return ex.data[..., 0], ex.data[..., 1], ex.valid, ex.dropped
 
     fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P()),
     )
-    s, d, v, drop = fn(src, dst)
+    with jax.named_scope("redistribute"):
+        s, d, v, drop = fn(src, dst)
     return OwnedEdges(s, d, v, drop)
 
 
@@ -113,31 +117,37 @@ def redistribute_sorted(
     cap = capacity or _default_capacity(cfg, nb)
 
     def per_shard(src_l, dst_l):
-        order = jnp.argsort(src_l)                         # send-side sort
-        src_s, dst_s = src_l[order], dst_l[order]
-        pair = jnp.stack([src_s, dst_s], axis=-1)
-        ex = capacity_all_to_all(pair, src_s // B, axis=axis, capacity=cap)
-        rs, rd, rv = ex.data[..., 0], ex.data[..., 1], ex.valid
-        # receive-side k-way sorted merge; sentinel-key the empty slots.
-        sentinel = jnp.asarray(cfg.n, rs.dtype)
-        keys = jnp.where(rv, rs, sentinel)
-        payload = jnp.stack([rd, rv.astype(rd.dtype)], axis=-1)
-        mkeys, mpay = merge_sorted_runs(keys, payload)
-        mvalid = mpay[..., 1].astype(jnp.bool_)
-        msrc = jnp.where(mvalid, mkeys, 0)
-        mdst = mpay[..., 0]
-        # keep the [nb, cap] layout (flattened view is sorted)
-        return (
-            msrc.reshape(nb, cap),
-            mdst.reshape(nb, cap),
-            mvalid.reshape(nb, cap),
-            ex.dropped,
-        )
+        with jax.named_scope("sort"):
+            order = jnp.argsort(src_l)                     # send-side sort
+        with jax.named_scope("permute"):
+            src_s, dst_s = src_l[order], dst_l[order]
+            pair = jnp.stack([src_s, dst_s], axis=-1)
+        with jax.named_scope("place"):
+            owner = src_s // B
+        ex = capacity_all_to_all(pair, owner, axis=axis, capacity=cap)
+        with jax.named_scope("merge"):
+            rs, rd, rv = ex.data[..., 0], ex.data[..., 1], ex.valid
+            # receive-side k-way sorted merge; sentinel-key the empty slots.
+            sentinel = jnp.asarray(cfg.n, rs.dtype)
+            keys = jnp.where(rv, rs, sentinel)
+            payload = jnp.stack([rd, rv.astype(rd.dtype)], axis=-1)
+            mkeys, mpay = merge_sorted_runs(keys, payload)
+            mvalid = mpay[..., 1].astype(jnp.bool_)
+            msrc = jnp.where(mvalid, mkeys, 0)
+            mdst = mpay[..., 0]
+            # keep the [nb, cap] layout (flattened view is sorted)
+            return (
+                msrc.reshape(nb, cap),
+                mdst.reshape(nb, cap),
+                mvalid.reshape(nb, cap),
+                ex.dropped,
+            )
 
     fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P()),
     )
-    s, d, v, drop = fn(src, dst)
+    with jax.named_scope("redistribute"):
+        s, d, v, drop = fn(src, dst)
     return OwnedEdges(s, d, v, drop)

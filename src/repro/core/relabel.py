@@ -63,25 +63,30 @@ def _relabel_field_ring(field: jnp.ndarray, pv_local: jnp.ndarray, *, bid, nb: i
            handled by the caller/kernel, correctness does not require it).
     pv_local: [B] this shard's pv chunk.
     """
-    sort_idx = jnp.argsort(field)            # paper: chunk-sort by endpoint
-    sorted_field = field[sort_idx]
-    out_sorted = jnp.zeros_like(sorted_field)
+    with jax.named_scope("sort"):
+        sort_idx = jnp.argsort(field)        # paper: chunk-sort by endpoint
+    with jax.named_scope("permute"):
+        sorted_field = field[sort_idx]
+    with jax.named_scope("lookup"):
+        out_sorted = jnp.zeros_like(sorted_field)
 
     def round_body(r, carry):
         pv_chunk, out = carry
-        chunk_owner = (bid + r) % nb
-        base = chunk_owner * B
-        local = sorted_field - base
-        in_range = (local >= 0) & (local < B)
-        idx = jnp.clip(local, 0, B - 1)
-        gathered = pv_chunk[idx]              # monotone gather (edges sorted)
-        out = jnp.where(in_range, gathered, out)
+        with jax.named_scope("lookup"):
+            chunk_owner = (bid + r) % nb
+            base = chunk_owner * B
+            local = sorted_field - base
+            in_range = (local >= 0) & (local < B)
+            idx = jnp.clip(local, 0, B - 1)
+            gathered = pv_chunk[idx]          # monotone gather (edges sorted)
+            out = jnp.where(in_range, gathered, out)
         pv_chunk = ring_shift(pv_chunk, axis) if nb > 1 else pv_chunk
         return pv_chunk, out
 
     _, out_sorted = lax.fori_loop(0, nb, round_body, (pv_local, out_sorted))
-    # scatter back to generation order
-    return jnp.zeros_like(field).at[sort_idx].set(out_sorted)
+    with jax.named_scope("permute"):
+        # scatter back to generation order
+        return jnp.zeros_like(field).at[sort_idx].set(out_sorted)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "axis"))
@@ -99,7 +104,8 @@ def relabel_ring(
     B = cfg.bucket_size
 
     def per_shard(src_l, dst_l, pv_l):
-        bid = lax.axis_index(axis)
+        with jax.named_scope("lookup"):
+            bid = lax.axis_index(axis)
         new_dst = _relabel_field_ring(dst_l, pv_l, bid=bid, nb=nb, B=B, axis=axis)
         new_src = _relabel_field_ring(src_l, pv_l, bid=bid, nb=nb, B=B, axis=axis)
         return new_src, new_dst
@@ -110,7 +116,8 @@ def relabel_ring(
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis)),
     )
-    return fn(src, dst, pv)
+    with jax.named_scope("relabel"):
+        return fn(src, dst, pv)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "axis"))
@@ -132,8 +139,9 @@ def relabel_recompute(
     from .shuffle import graph_perm
 
     del mesh, axis  # no collectives: the whole point
-    return (graph_perm(cfg.seed, src, cfg.n, rounds=cfg.feistel_rounds),
-            graph_perm(cfg.seed, dst, cfg.n, rounds=cfg.feistel_rounds))
+    with jax.named_scope("relabel"), jax.named_scope("lookup"):
+        return (graph_perm(cfg.seed, src, cfg.n, rounds=cfg.feistel_rounds),
+                graph_perm(cfg.seed, dst, cfg.n, rounds=cfg.feistel_rounds))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "axis", "capacity"))
@@ -159,13 +167,17 @@ def relabel_alltoall(
         capacity = int(cfg.capacity_factor * per_shard_q / max(nb, 1)) + 8
 
     def per_shard(src_l, dst_l, pv_l):
-        q = jnp.concatenate([src_l, dst_l])            # both fields, one trip
-        ex = capacity_all_to_all(q, q // B, axis=axis, capacity=capacity)
-        base = lax.axis_index(axis) * B
-        local = jnp.clip(ex.data - base, 0, B - 1)
-        answered = jnp.where(ex.valid, pv_l[local], 0)
+        with jax.named_scope("place"):
+            q = jnp.concatenate([src_l, dst_l])        # both fields, one trip
+            owner = q // B
+        ex = capacity_all_to_all(q, owner, axis=axis, capacity=capacity)
+        with jax.named_scope("lookup"):
+            base = lax.axis_index(axis) * B
+            local = jnp.clip(ex.data - base, 0, B - 1)
+            answered = jnp.where(ex.valid, pv_l[local], 0)
         back = return_all_to_all(answered, ex.position, axis=axis)
-        new_src, new_dst = jnp.split(back, 2)
+        with jax.named_scope("place"):
+            new_src, new_dst = jnp.split(back, 2)
         return new_src, new_dst, ex.dropped
 
     fn = jax.shard_map(
@@ -174,4 +186,5 @@ def relabel_alltoall(
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P()),
     )
-    return fn(src, dst, pv)
+    with jax.named_scope("relabel"):
+        return fn(src, dst, pv)

@@ -55,19 +55,25 @@ def _local_shuffle(buf: jnp.ndarray, salt: jnp.ndarray) -> jnp.ndarray:
     a subset of a permutation of [0:n)) and a per-round salt, so the schedule
     is deterministic, reproducible, and needs no RNG state.
     """
-    keys = mix32(buf.astype(jnp.uint32) ^ salt)
-    return buf[jnp.argsort(keys)]
+    with jax.named_scope("rng"):
+        keys = mix32(buf.astype(jnp.uint32) ^ salt)
+    with jax.named_scope("sort"):
+        order = jnp.argsort(keys)
+    with jax.named_scope("permute"):
+        return buf[order]
 
 
 def _shuffle_rounds_body(nb: int, axis: str, seed: int):
     def body(r, sbuf):
-        salt = mix32(jnp.uint32(seed) + jnp.uint32(r) * jnp.uint32(0x9E3779B9))
+        with jax.named_scope("rng"):
+            salt = mix32(jnp.uint32(seed) + jnp.uint32(r) * jnp.uint32(0x9E3779B9))
         sbuf = _local_shuffle(sbuf, salt)
         if nb > 1:
             blk = sbuf.shape[0] // nb
             pieces = sbuf.reshape(nb, blk)
             # Alg. 2/3: slice j of my buffer -> node j; my slice stays (line 6).
-            pieces = lax.all_to_all(pieces, axis, split_axis=0, concat_axis=0, tiled=False)
+            with jax.named_scope("collective"):
+                pieces = lax.all_to_all(pieces, axis, split_axis=0, concat_axis=0, tiled=False)
             sbuf = pieces.reshape(-1)
         return sbuf
 
@@ -84,17 +90,19 @@ def distributed_shuffle(cfg: GraphConfig, mesh: Mesh, axis: str = "shards") -> j
     rounds = cfg.rounds
 
     def per_shard(_):
-        bid = lax.axis_index(axis)
-        # sbuf initialized to this shard's range partition of [0:n)  (RP(n, nb))
-        sbuf = bid * B + jnp.arange(B, dtype=cfg.vertex_dtype)
+        with jax.named_scope("rng"):
+            bid = lax.axis_index(axis)
+            # sbuf initialized to this shard's range partition of [0:n)  (RP(n, nb))
+            sbuf = bid * B + jnp.arange(B, dtype=cfg.vertex_dtype)
         sbuf = lax.fori_loop(0, rounds, _shuffle_rounds_body(nb, axis, cfg.seed), sbuf)
         return sbuf
 
     shard_fn = jax.shard_map(
         per_shard, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis)
     )
-    dummy = jnp.zeros((nb,), jnp.int32)  # carries the axis, no data
-    return shard_fn(dummy)
+    with jax.named_scope("shuffle"):
+        dummy = jnp.zeros((nb,), jnp.int32)  # carries the axis, no data
+        return shard_fn(dummy)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "axis"))
@@ -108,13 +116,16 @@ def shuffle_argsort(cfg: GraphConfig, mesh: Mesh, axis: str = "shards") -> jnp.n
     """
     n = cfg.n
     sharding = NamedSharding(mesh, P(axis))
-    ids = jnp.arange(n, dtype=cfg.vertex_dtype)
-    ids = lax.with_sharding_constraint(ids, sharding)
-    keys = mix32(ids.astype(jnp.uint32) + jnp.uint32(cfg.seed))
-    # sort (keys, ids) pairs by key: ids land in uniformly-random order.
-    # mix32 is bijective => no duplicate keys => exact uniform permutation.
-    _, pv = lax.sort([keys, ids], dimension=0, num_keys=1)
-    return lax.with_sharding_constraint(pv, sharding)
+    with jax.named_scope("shuffle"):
+        with jax.named_scope("rng"):
+            ids = jnp.arange(n, dtype=cfg.vertex_dtype)
+            ids = lax.with_sharding_constraint(ids, sharding)
+            keys = mix32(ids.astype(jnp.uint32) + jnp.uint32(cfg.seed))
+        # sort (keys, ids) pairs by key: ids land in uniformly-random order.
+        # mix32 is bijective => no duplicate keys => exact uniform permutation.
+        with jax.named_scope("sort"):
+            _, pv = lax.sort([keys, ids], dimension=0, num_keys=1)
+            return lax.with_sharding_constraint(pv, sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -185,10 +196,11 @@ def shuffle_recompute(cfg: GraphConfig, mesh: Mesh, axis: str = "shards") -> jnp
     state beyond the output itself.  Requires cfg.scale <= 31 (vertex ids
     must fit the uint32 Feistel container)."""
     sharding = NamedSharding(mesh, P(axis))
-    ids = lax.with_sharding_constraint(
-        jnp.arange(cfg.n, dtype=cfg.vertex_dtype), sharding)
-    pv = graph_perm(cfg.seed, ids, cfg.n, rounds=cfg.feistel_rounds)
-    return lax.with_sharding_constraint(pv, sharding)
+    with jax.named_scope("shuffle"), jax.named_scope("rng"):
+        ids = lax.with_sharding_constraint(
+            jnp.arange(cfg.n, dtype=cfg.vertex_dtype), sharding)
+        pv = graph_perm(cfg.seed, ids, cfg.n, rounds=cfg.feistel_rounds)
+        return lax.with_sharding_constraint(pv, sharding)
 
 
 def pv_is_permutation(pv: jnp.ndarray) -> jnp.ndarray:

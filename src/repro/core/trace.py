@@ -47,6 +47,12 @@ per (host, pid, tid) lane) holds for the call-structured categories
 events that generator interleaving may close out of LIFO order, so
 `validate_timeline` exempts them.
 
+The device path has its own two pieces here: `phase_span`, the host span
+of one device phase (on the profiler's clock, and in the Tracer's log when
+one is installed), and the vocabulary of `jax.named_scope`s that the phase
+programs open (PHASE_SCOPES, EXCHANGE_SCOPE, KIND_SCOPES), which the device
+trace's operations carry through the optimised HLO.
+
 `python -m repro.core.trace lint` asserts every kernel registered in
 phases._KERNELS (the universe phase_task_plan draws from) carries the
 instrumentation wrapper — the CI guard against a new kernel silently
@@ -273,6 +279,43 @@ def uninstall_tracer() -> None:
     with _INSTALL_LOCK:
         tr, _TRACER = _TRACER, _NULL
     tr.close()
+
+
+# ---------------------------------------------------------------------------
+# The device path: host spans on the profiler's clock, scopes in the programs
+# ---------------------------------------------------------------------------
+
+# `jax.named_scope`s of the device phases.  Every operation of a phase
+# program lies under its phase scope and under exactly one kind scope;
+# capacity_all_to_all and return_all_to_all add `exchange` between the two,
+# whichever phase calls them.  The scopes reach the optimised HLO as each
+# instruction's op_name, and from there the device trace's operations.
+PHASE_SCOPES = ("shuffle", "edges", "relabel", "redistribute", "csr")
+EXCHANGE_SCOPE = "exchange"
+KIND_SCOPES = (
+    "sort",        # every argsort
+    "permute",     # gathers and scatters by a sort order
+    "lookup",      # reading pv at an edge's endpoint
+    "place",       # destinations, slot scatters and counts of the exchange and the CSR
+    "search",      # searchsorted and the ranks it gives
+    "collective",  # all_to_all, ppermute, psum
+    "merge",       # merge_sorted_runs and its sentinel keys
+    "rng",         # edge and shuffle keys
+)
+
+
+@contextlib.contextmanager
+def phase_span(name: str, **args):
+    """A host span of the device path (`gen.relabel`, ...): a
+    `jax.profiler.TraceAnnotation`, so it lies on the device trace's clock
+    and names the idle gaps under it, and a `cat="phase"` span of the
+    installed Tracer, so `launch.cluster trace` timelines hold the device
+    path too.  jax is imported here, not at module level: the disk tier
+    imports this module and stays free of it."""
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation(name, **args), get_tracer().span(name, cat="phase", **args):
+        yield
 
 
 # ---------------------------------------------------------------------------

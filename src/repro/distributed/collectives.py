@@ -77,31 +77,34 @@ def bucket_by_destination(data: jnp.ndarray, dest: jnp.ndarray, k: int, capacity
     carry fixed-size buffers with dead slots (data/walks.py).
     """
     n = dest.shape[0]
-    dest = dest.astype(jnp.int32)
-    if valid is not None:
-        dest = jnp.where(valid, dest, k)                          # sentinel group
+    with jax.named_scope("place"):
+        dest = dest.astype(jnp.int32)
+        if valid is not None:
+            dest = jnp.where(valid, dest, k)                      # sentinel group
     # Rank of each record within its destination group, via stable sort:
-    order = jnp.argsort(dest, stable=True)                       # [N]
-    sorted_dest = dest[order]
-    # start offset of each destination group among the sorted records
-    group_start = jnp.searchsorted(sorted_dest, jnp.arange(k, dtype=jnp.int32), side="left")
-    rank_sorted = jnp.arange(n, dtype=jnp.int32) - group_start[jnp.minimum(sorted_dest, k - 1)]
-    rank = jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted)  # rank within dest group
-    keep = (rank < capacity) & (dest < k)
-    slot = jnp.where(keep, dest * capacity + rank, k * capacity)  # overflow -> scratch slot
-    flat_shape = (k * capacity + 1,) + data.shape[1:]
-    flat = jnp.zeros(flat_shape, data.dtype).at[slot].set(data, mode="drop")
-    # Occupancy is marked in int32, not bool: a large bool scatter (and the
-    # bool all_to_all after it) takes the TPU compiler minutes and several
-    # times the host memory of the int32 one.
-    occupied = jnp.zeros((k * capacity + 1,), jnp.int32).at[slot].set(1, mode="drop")
-    dropped = jnp.sum((rank >= capacity) & (dest < k)).astype(jnp.int32)
-    return Buckets(
-        data=flat[:-1].reshape((k, capacity) + data.shape[1:]),
-        valid=occupied[:-1].reshape(k, capacity) == 1,
-        position=slot,
-        dropped=dropped,
-    )
+    with jax.named_scope("sort"):
+        order = jnp.argsort(dest, stable=True)                   # [N]
+    with jax.named_scope("permute"):
+        sorted_dest = dest[order]
+    with jax.named_scope("search"):
+        # start offset of each destination group among the sorted records
+        group_start = jnp.searchsorted(sorted_dest, jnp.arange(k, dtype=jnp.int32), side="left")
+        rank_sorted = jnp.arange(n, dtype=jnp.int32) - group_start[jnp.minimum(sorted_dest, k - 1)]
+    with jax.named_scope("permute"):
+        rank = jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted)  # rank within dest group
+    with jax.named_scope("place"):
+        keep = (rank < capacity) & (dest < k)
+        slot = jnp.where(keep, dest * capacity + rank, k * capacity)  # overflow -> scratch slot
+        flat_shape = (k * capacity + 1,) + data.shape[1:]
+        flat = jnp.zeros(flat_shape, data.dtype).at[slot].set(data, mode="drop")
+        # Occupancy is marked in int32, not bool: a large bool scatter (and the
+        # bool all_to_all after it) takes the TPU compiler minutes and several
+        # times the host memory of the int32 one.
+        occupied = jnp.zeros((k * capacity + 1,), jnp.int32).at[slot].set(1, mode="drop")
+        dropped = jnp.sum((rank >= capacity) & (dest < k)).astype(jnp.int32)
+        data_rows = flat[:-1].reshape((k, capacity) + data.shape[1:])
+        valid_rows = occupied[:-1].reshape(k, capacity) == 1
+    return Buckets(data=data_rows, valid=valid_rows, position=slot, dropped=dropped)
 
 
 def unbucket(buckets_data: jnp.ndarray, position: jnp.ndarray, fill=0) -> jnp.ndarray:
@@ -111,10 +114,11 @@ def unbucket(buckets_data: jnp.ndarray, position: jnp.ndarray, fill=0) -> jnp.nd
     Dropped records receive `fill`.
     """
     k, capacity = buckets_data.shape[:2]
-    flat = buckets_data.reshape((k * capacity,) + buckets_data.shape[2:])
-    pad = jnp.full((1,) + flat.shape[1:], fill, flat.dtype)
-    flat = jnp.concatenate([flat, pad], axis=0)
-    return flat[position]
+    with jax.named_scope("place"):
+        flat = buckets_data.reshape((k * capacity,) + buckets_data.shape[2:])
+        pad = jnp.full((1,) + flat.shape[1:], fill, flat.dtype)
+        flat = jnp.concatenate([flat, pad], axis=0)
+        return flat[position]
 
 
 # ---------------------------------------------------------------------------
@@ -144,12 +148,14 @@ def capacity_all_to_all(
     valid=False are discarded without consuming capacity.
     """
     k = lax.axis_size(axis)
-    b = bucket_by_destination(data, dest, k, capacity, valid=valid)
-    recv = lax.all_to_all(b.data, axis, split_axis=0, concat_axis=0, tiled=False)
-    # the mask crosses as int32 for the reason bucket_by_destination marks it so
-    recv_valid = lax.all_to_all(b.valid.astype(jnp.int32), axis, split_axis=0,
-                                concat_axis=0, tiled=False) == 1
-    dropped = lax.psum(b.dropped, axis)
+    with jax.named_scope("exchange"):
+        b = bucket_by_destination(data, dest, k, capacity, valid=valid)
+        with jax.named_scope("collective"):
+            recv = lax.all_to_all(b.data, axis, split_axis=0, concat_axis=0, tiled=False)
+            # the mask crosses as int32 for the reason bucket_by_destination marks it so
+            recv_valid = lax.all_to_all(b.valid.astype(jnp.int32), axis, split_axis=0,
+                                        concat_axis=0, tiled=False) == 1
+            dropped = lax.psum(b.dropped, axis)
     return ExchangeResult(recv, recv_valid, b.position, dropped)
 
 
@@ -165,8 +171,10 @@ def return_all_to_all(
 
     `results` is [k, capacity, ...] aligned with ExchangeResult.data.
     """
-    back = lax.all_to_all(results, axis, split_axis=0, concat_axis=0, tiled=False)
-    return unbucket(back, position, fill=fill)
+    with jax.named_scope("exchange"):
+        with jax.named_scope("collective"):
+            back = lax.all_to_all(results, axis, split_axis=0, concat_axis=0, tiled=False)
+        return unbucket(back, position, fill=fill)
 
 
 # ---------------------------------------------------------------------------
@@ -186,7 +194,8 @@ def ring_shift(x: jnp.ndarray, axis: str, shift: int = 1) -> jnp.ndarray:
     """
     k = lax.axis_size(axis)
     perm = [(i, (i - shift) % k) for i in range(k)]  # (source, destination)
-    return lax.ppermute(x, axis, perm)
+    with jax.named_scope("collective"):
+        return lax.ppermute(x, axis, perm)
 
 
 # ---------------------------------------------------------------------------

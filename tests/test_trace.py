@@ -339,3 +339,54 @@ def test_run_phase_emits_nothing_when_untraced(tmp_path):
 def test_trace_cli_lint_entry():
     assert trace_mod.main(["lint"]) == 0
     assert trace_mod.main([]) == 2
+
+
+# ---------------------------------------------------------------------------
+# The device path: phase_span and generate()'s spans
+# ---------------------------------------------------------------------------
+
+
+def test_phase_span_records_a_phase_span_when_traced(tmp_path):
+    tr = install_tracer(str(tmp_path))
+    with trace_mod.phase_span("gen.relabel", seed=7):
+        pass
+    tr.flush()
+    recs = _read_jsonl(tr.path)
+    assert [(r["name"], r["cat"], r["args"]) for r in recs] == [
+        ("gen.relabel", "phase", {"seed": 7})]
+
+
+def test_phase_span_writes_nothing_untraced(tmp_path):
+    with trace_mod.phase_span("gen.csr", seed=1):
+        pass
+    assert not get_tracer().enabled
+    assert not os.path.exists(tmp_path / "trace")
+
+
+def test_generate_spans_each_phase_with_the_graphs_seed(tmp_path):
+    from repro.core.pipeline import generate
+    from repro.core.types import GraphConfig
+
+    tr = install_tracer(str(tmp_path))
+    cfg = GraphConfig(scale=8, seed=1234)
+    generate(cfg)
+    tr.flush()
+    recs = [r for r in _read_jsonl(tr.path) if r["cat"] == "phase"]
+    assert [r["name"] for r in recs] == [
+        "gen.shuffle", "gen.edges", "gen.relabel", "gen.redistribute", "gen.csr"]
+    assert all(r["args"] == {"seed": 1234} for r in recs)
+    assert validate_timeline(recs) == []
+
+
+def test_compile_phases_compiles_what_generate_runs_and_runs_nothing():
+    from repro.core.pipeline import compile_phases
+    from repro.core.types import GraphConfig
+
+    cfg = GraphConfig(scale=8)
+    compiled = compile_phases(cfg)
+    assert sorted(compiled) == ["build_csr_sorted", "distributed_shuffle", "generate_edges",
+                                "redistribute_sorted", "relabel_ring"]
+    text = compiled["relabel_ring"].as_text()
+    assert "/relabel/sort/" in text and "/lookup/" in text
+    recompute = compile_phases(cfg, shuffle_variant="recompute")
+    assert "shuffle_recompute" in recompute and "relabel_recompute" in recompute
