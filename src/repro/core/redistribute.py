@@ -13,8 +13,8 @@ the binomial fluctuation plus residual high-degree-vertex skew (the paper's
 
 Two variants, mirroring the paper:
   redistribute            unordered (paper's implemented version, §III-B5)
-  redistribute_sorted     §III-B7: senders pre-sort by new source; the
-                          stable bucketing preserves sortedness per packet;
+  redistribute_sorted     §III-B7: senders pre-sort by new source, so each
+                          packet is one contiguous run of sorted records;
                           the receiver k-way-merges the nb sorted runs =>
                           its edges arrive globally sorted by source and the
                           CSR build degenerates to the trivial Alg. 1.
@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..distributed.collectives import capacity_all_to_all, merge_sorted_runs
+from ..distributed.collectives import ExchangeResult, capacity_all_to_all, merge_sorted_runs
 from .types import GraphConfig
 
 
@@ -97,6 +97,28 @@ def redistribute(
     return OwnedEdges(s, d, v, drop)
 
 
+def merge_received(ex: ExchangeResult, n: int):
+    """Receive side of redistribute_sorted: k-way merge of the nb sorted
+    packets that arrived, empty slots keyed past every vertex (`n`) so they
+    sink to the end.  Returns (src, dst, valid, dropped) in the [nb, cap]
+    layout, whose flattened view is sorted by src."""
+    nb, cap = ex.valid.shape
+    with jax.named_scope("merge"):
+        rs, rd, rv = ex.data[..., 0], ex.data[..., 1], ex.valid
+        keys = jnp.where(rv, rs, jnp.asarray(n, rs.dtype))
+        payload = jnp.stack([rd, rv.astype(rd.dtype)], axis=-1)
+        mkeys, mpay = merge_sorted_runs(keys, payload)
+        mvalid = mpay[..., 1].astype(jnp.bool_)
+        msrc = jnp.where(mvalid, mkeys, 0)
+        mdst = mpay[..., 0]
+        return (
+            msrc.reshape(nb, cap),
+            mdst.reshape(nb, cap),
+            mvalid.reshape(nb, cap),
+            ex.dropped,
+        )
+
+
 @partial(jax.jit, static_argnames=("cfg", "mesh", "axis", "capacity"))
 def redistribute_sorted(
     cfg: GraphConfig,
@@ -108,9 +130,10 @@ def redistribute_sorted(
 ) -> OwnedEdges:
     """Sorted-merge redistribute (paper §III-B7, proposed-not-implemented).
 
-    Sort locally by (new) src; stable bucketing keeps each packet sorted;
-    receiver merges its nb sorted runs (invalid slots are key-maxed so they
-    sink to the end).  Output flattened arrays are globally sorted by src.
+    Sort locally by (new) src; each packet is then one contiguous run of
+    the sorted records, so it is sorted too; receiver merges its nb sorted
+    runs (invalid slots are key-maxed so they sink to the end).  Output
+    flattened arrays are globally sorted by src.
     """
     nb = mesh.shape[axis]
     B = cfg.bucket_size
@@ -124,24 +147,9 @@ def redistribute_sorted(
             pair = jnp.stack([src_s, dst_s], axis=-1)
         with jax.named_scope("place"):
             owner = src_s // B
-        ex = capacity_all_to_all(pair, owner, axis=axis, capacity=cap)
-        with jax.named_scope("merge"):
-            rs, rd, rv = ex.data[..., 0], ex.data[..., 1], ex.valid
-            # receive-side k-way sorted merge; sentinel-key the empty slots.
-            sentinel = jnp.asarray(cfg.n, rs.dtype)
-            keys = jnp.where(rv, rs, sentinel)
-            payload = jnp.stack([rd, rv.astype(rd.dtype)], axis=-1)
-            mkeys, mpay = merge_sorted_runs(keys, payload)
-            mvalid = mpay[..., 1].astype(jnp.bool_)
-            msrc = jnp.where(mvalid, mkeys, 0)
-            mdst = mpay[..., 0]
-            # keep the [nb, cap] layout (flattened view is sorted)
-            return (
-                msrc.reshape(nb, cap),
-                mdst.reshape(nb, cap),
-                mvalid.reshape(nb, cap),
-                ex.dropped,
-            )
+        # sorted by src, so the owners are too: each bucket is one run
+        ex = capacity_all_to_all(pair, owner, axis=axis, capacity=cap, dest_sorted=True)
+        return merge_received(ex, cfg.n)
 
     fn = jax.shard_map(
         per_shard, mesh=mesh,

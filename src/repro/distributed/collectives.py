@@ -70,9 +70,9 @@ def bucket_by_destination(data: jnp.ndarray, dest: jnp.ndarray, k: int, capacity
     """Stable bucket of `data` rows by `dest` in [0, k) with fixed capacity.
 
     Paper Alg. 8 lines 2-7 ("append to elp_d; if full, send") under static
-    shapes.  Stability (records to the same destination keep their relative
-    order) is what lets the sorted-merge redistribute variant (§III-B7) ship
-    pre-sorted runs.  Rows with valid=False are discarded silently (they
+    shapes.  Stable: records to the same destination keep their relative
+    order (for a sorted `dest`, bucket_sorted_runs gives the same buckets
+    without the sort).  Rows with valid=False are discarded silently (they
     consume no capacity and are not counted as drops) — used by callers that
     carry fixed-size buffers with dead slots (data/walks.py).
     """
@@ -104,6 +104,37 @@ def bucket_by_destination(data: jnp.ndarray, dest: jnp.ndarray, k: int, capacity
         dropped = jnp.sum((rank >= capacity) & (dest < k)).astype(jnp.int32)
         data_rows = flat[:-1].reshape((k, capacity) + data.shape[1:])
         valid_rows = occupied[:-1].reshape(k, capacity) == 1
+    return Buckets(data=data_rows, valid=valid_rows, position=slot, dropped=dropped)
+
+
+def bucket_sorted_runs(data: jnp.ndarray, dest: jnp.ndarray, k: int, capacity: int) -> Buckets:
+    """bucket_by_destination for a non-decreasing `dest` in [0, k), with no
+    sort and no scatter: the same Buckets, bit for bit.
+
+    Each destination's records are then one contiguous run of the input, in
+    the input's order, and a record's rank is its distance from the start of
+    its run.  So row d is the run's first `capacity` records, one slice of
+    the input; k boundary searches find the runs.
+    """
+    n = dest.shape[0]
+    with jax.named_scope("search"):
+        dest = dest.astype(jnp.int32)
+        starts = jnp.searchsorted(dest, jnp.arange(k + 1, dtype=jnp.int32),
+                                  side="left").astype(jnp.int32)
+        counts = starts[1:] - starts[:-1]
+    with jax.named_scope("place"):
+        # padded so that a slice from any start <= n stays inside the array
+        padded = jnp.concatenate(
+            [data, jnp.zeros((capacity,) + data.shape[1:], data.dtype)], axis=0)
+        rows = jnp.stack([lax.dynamic_slice_in_dim(padded, starts[d], capacity)
+                          for d in range(k)])
+        valid_rows = jnp.arange(capacity, dtype=jnp.int32) < jnp.minimum(counts, capacity)[:, None]
+        # empty slots hold 0, as bucket_by_destination leaves them
+        mask = valid_rows.reshape(valid_rows.shape + (1,) * (data.ndim - 1))
+        data_rows = jnp.where(mask, rows, jnp.zeros((), data.dtype))
+        dropped = jnp.sum(jnp.maximum(counts - capacity, 0)).astype(jnp.int32)
+        rank = jnp.arange(n, dtype=jnp.int32) - starts[dest]
+        slot = jnp.where(rank < capacity, dest * capacity + rank, k * capacity)
     return Buckets(data=data_rows, valid=valid_rows, position=slot, dropped=dropped)
 
 
@@ -140,16 +171,24 @@ def capacity_all_to_all(
     axis: str,
     capacity: int,
     valid: Optional[jnp.ndarray] = None,
+    dest_sorted: bool = False,
 ) -> ExchangeResult:
     """Bucket records by destination shard and exchange them (k:1 pattern).
 
     Must be called inside shard_map over `axis`.  `data` is [N, ...] local
     records, `dest` [N] destination shard ids in [0, k).  Rows with
-    valid=False are discarded without consuming capacity.
+    valid=False are discarded without consuming capacity.  A caller whose
+    `dest` is non-decreasing, because it sorted its records by destination
+    itself, says so with dest_sorted=True: the buckets are then its runs
+    (bucket_sorted_runs), with no sort and no scatter.
     """
     k = lax.axis_size(axis)
     with jax.named_scope("exchange"):
-        b = bucket_by_destination(data, dest, k, capacity, valid=valid)
+        if dest_sorted:
+            assert valid is None, "the run bucketing takes every row"
+            b = bucket_sorted_runs(data, dest, k, capacity)
+        else:
+            b = bucket_by_destination(data, dest, k, capacity, valid=valid)
         with jax.named_scope("collective"):
             recv = lax.all_to_all(b.data, axis, split_axis=0, concat_axis=0, tiled=False)
             # the mask crosses as int32 for the reason bucket_by_destination marks it so

@@ -9,7 +9,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core.types import GraphConfig, owner_of, quadrant_thresholds
 from repro.distributed.collectives import (
-    bucket_by_destination, merge_sorted_runs, merge_two_sorted, unbucket)
+    bucket_by_destination, bucket_sorted_runs, merge_sorted_runs, merge_two_sorted, unbucket)
 from repro.kernels import ref
 from repro.serve.sampling import SamplingParams, sample
 from repro.train.fault import StragglerPolicy
@@ -51,6 +51,36 @@ def test_bucket_invariants(n, k, cap_frac, seed):
     kept = back != -1
     np.testing.assert_array_equal(back[kept], data[kept])
     assert kept.sum() == n - exp_dropped
+
+
+@SETTINGS
+@given(
+    n=st.integers(1, 300),
+    k=st.integers(1, 8),
+    cap_frac=st.floats(0.1, 3.0),
+    seed=st.integers(0, 2**31 - 1),
+    width=st.sampled_from([(), (2,)]),
+)
+def test_sorted_runs_bucket_as_the_general_bucketing(n, k, cap_frac, seed, width):
+    """On a non-decreasing dest the run bucketing gives bucket_by_destination's
+    result bit for bit, empty and overflowing destinations included."""
+    rng = np.random.default_rng(seed)
+    used = rng.choice(k, size=rng.integers(1, k + 1), replace=False)  # others stay empty
+    dest = np.sort(rng.choice(used, n)).astype(np.int32)
+    data = rng.integers(0, 1 << 30, (n,) + width).astype(np.int32)
+    capacity = max(1, int(n * cap_frac / k))
+    want = bucket_by_destination(jnp.asarray(data), jnp.asarray(dest), k, capacity)
+    got = bucket_sorted_runs(jnp.asarray(data), jnp.asarray(dest), k, capacity)
+    for field in want._fields:
+        w, g = np.asarray(getattr(want, field)), np.asarray(getattr(got, field))
+        assert g.dtype == w.dtype and g.shape == w.shape, field
+        np.testing.assert_array_equal(g, w, err_msg=field)
+    # round trip: unbucket returns every kept record to its origin
+    back = np.asarray(unbucket(got.data, got.position, fill=-1))
+    kept = np.asarray(got.position) < k * capacity
+    np.testing.assert_array_equal(back[kept], data[kept])
+    assert (back[~kept] == -1).all()
+    assert kept.sum() == n - int(got.dropped)
 
 
 @SETTINGS

@@ -111,9 +111,13 @@ def _is_constant_splat(ins, comp) -> bool:
         o in comp.instrs and comp.instrs[o].op == "constant" for o in ins.operands)
 
 
-def uncovered(text: str):
+def instructions(text: str, skip):
+    """(computation, instruction, op_name) of every instruction reached from
+    the entry computation, each with its own traced op_name or, lacking one,
+    that of the fusion or loop it lies in (None outside any).  An
+    instruction for which skip(instruction, computation) holds is passed
+    over, with what it calls."""
     comps, entry = parse_module(text)
-    bad = []
     seen = set()
 
     def visit(cname, inherited):
@@ -122,22 +126,29 @@ def uncovered(text: str):
         seen.add(cname)
         comp = comps[cname]
         for ins in comp.instrs.values():
-            if ins.op in BOOKKEEPING or _is_constant_splat(ins, comp):
+            if skip(ins, comp):
                 continue
             m = OP_NAME.search(ins.attrs)
             traced = m is not None and m.group(1).startswith("jit(")
             name = m.group(1) if traced else inherited
             for _, callee in CALLS.findall(ins.attrs):
-                visit(callee, name)
-            if name is None:
-                continue  # made by the compiler or a lowering rule, outside any fusion
-            if ins.op == "while" or LOOP_CONTROL.search(name.split(";")[0]):
-                continue
-            v = verdict(name)
-            if v != "ok":
-                bad.append((cname, ins.name, ins.op, f"{v}: {name}"))
+                yield from visit(callee, name)
+            yield comp, ins, name
 
-    visit(entry, None)
+    yield from visit(entry, None)
+
+
+def uncovered(text: str):
+    bad = []
+    skip = lambda ins, comp: ins.op in BOOKKEEPING or _is_constant_splat(ins, comp)  # noqa: E731
+    for comp, ins, name in instructions(text, skip):
+        if name is None:
+            continue  # made by the compiler or a lowering rule, outside any fusion
+        if ins.op == "while" or LOOP_CONTROL.search(name.split(";")[0]):
+            continue
+        v = verdict(name)
+        if v != "ok":
+            bad.append((comp.name, ins.name, ins.op, f"{v}: {name}"))
     return bad
 
 
@@ -162,6 +173,32 @@ def test_the_exchange_lies_under_the_phase_that_calls_it(hlo):
             assert inside == {(phase, EXCHANGE_SCOPE)}, (program, inside)
         else:
             assert not inside, (program, inside)
+
+
+def exchange_ops(text: str):
+    """{(op, kind)} of every instruction under `exchange`, fused ones
+    included, with the kind scope its op_name (or its fusion's) names."""
+    found = set()
+    for _, ins, name in instructions(text, lambda ins, comp: ins.op in BOOKKEEPING):
+        for part in (name or "").split(";"):
+            path = part.split("/")[:-1]
+            if EXCHANGE_SCOPE in path:
+                found.add((ins.op, next((p for p in path if p in KIND_SCOPES), None)))
+    return found
+
+
+def test_only_the_sorted_redistribute_buckets_by_runs(hlo):
+    """redistribute_sorted's records arrive sorted by owner, so its exchange
+    slices runs: no sort, no permute and no scatter.  The unsorted
+    redistribute and the all_to_all relabel keep the general bucketing."""
+    ops = exchange_ops(hlo["redistribute_sorted"])
+    assert ops and {kind for _, kind in ops} <= {"search", "place", "collective"}, ops
+    assert "scatter" not in {op for op, _ in ops}, ops
+    for program in ("redistribute", "relabel_alltoall"):
+        ops = exchange_ops(hlo[program])
+        kinds = {kind for _, kind in ops}
+        assert {"sort", "permute"} <= kinds, (program, kinds)
+        assert "scatter" in {op for op, _ in ops}, program
 
 
 def test_the_vocabulary_is_disjoint():

@@ -18,7 +18,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core.types import GraphConfig
 
@@ -123,3 +123,25 @@ def test_relabel_gather_kernel_compiles(one_chip):
     relabel_gather_pallas.lower(
         _shape((cfg.edges_per_shard,), one_chip), _shape((cfg.bucket_size,), one_chip),
         _shape((), one_chip), interpret=False).compile()
+
+
+def test_sorted_redistribute_buckets_without_a_scatter_at_cell_scale(mesh1):
+    """redistribute_sorted at the benchmark cell's shape (Graph500 scale 22,
+    nb=1, capacity_factor 1.0): the exchange slices the sorted records' runs,
+    so no scatter lies under `exchange`, and the program holds no more than
+    with the general bucketing: argument + output + temp 2,483,419,648 B
+    (536,870,912 + 805,319,680 + 1,141,229,056) there, read from the same
+    compile of the program that bucketed by a sort and a slot scatter."""
+    import re
+
+    from repro.core.redistribute import redistribute_sorted
+
+    cfg = GraphConfig(scale=22, nb=1, capacity_factor=1.0)
+    edges = jax.ShapeDtypeStruct((cfg.m,), jnp.int32, sharding=NamedSharding(mesh1, P("shards")))
+    compiled = redistribute_sorted.lower(cfg, mesh1, edges, edges).compile()
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes) <= 2_483_419_648
+    scatters = [line for line in compiled.as_text().splitlines()
+                if re.search(r" scatter\(", line) and "/exchange/" in line]
+    assert scatters == []
