@@ -16,7 +16,8 @@ cell once:
 4. runs units back to back until the first one that ends after --seconds;
    after each unit a device-side check counts it as failed if it broke an
    invariant, and a compile inside the window makes the run incorrect;
-5. compares the last unit with the plain reference (reference.py), and
+5. compares the last unit with the plain reference of its traffic kind
+   (reference.py, reference_walks.py), and
    every other unit with the last one by fingerprint;
 6. prints one JSON line: the end-to-end metrics with --trace 0, or with
    --trace 1 the per-layer metrics read from a profiler trace of the
@@ -41,7 +42,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+from typing import Callable, Dict, List, Optional  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
@@ -96,6 +97,8 @@ class Reading:
     trace: devtrace.TraceSummary
     graph: units.ref.GraphSpec
     peak: Optional[peaks.Peak]
+    programs: Callable[[], Optional[List[str]]]   # the window's programs' HLO texts
+    walks: Optional[units.ref_walks.WalkSpec] = None
     id_bytes: int = 4
 
 
@@ -114,6 +117,16 @@ def _memory_peak(devices) -> Optional[int]:
     peaks_seen = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
     peaks_seen = [p for p in peaks_seen if p is not None]
     return max(peaks_seen) if peaks_seen else None
+
+
+def _say_memory(devices, when: str) -> None:
+    """The fullest chip's memory in use, and its peak so far, to stderr:
+    read after set-up and after the window, they tell which of the two
+    set the process peak."""
+    stats = [d.memory_stats() or {} for d in devices]
+    fullest = max(stats, key=lambda s: s.get("peak_bytes_in_use", 0))
+    say(f"memory after {when}: in use {fullest.get('bytes_in_use')} "
+        f"peak {fullest.get('peak_bytes_in_use')} limit {fullest.get('bytes_limit')}")
 
 
 def _trace_options():
@@ -144,6 +157,7 @@ def _run(cell, seed, seconds, trace, devices, peak, log, keep_trace, t_start) ->
     traffic.setup()
     t_setup = time.perf_counter()
     setup_s = t_setup - t_start
+    _say_memory(devices[:nb], "set-up")
     for _, name, secs in log.between(t_start, t_setup):
         if secs >= 0.05:
             say(f"setup compile_s {name}: {secs!r}")
@@ -156,6 +170,7 @@ def _run(cell, seed, seconds, trace, devices, peak, log, keep_trace, t_start) ->
         if trace:
             jax.profiler.stop_trace()
         memory_peak = _memory_peak(devices[:nb])
+        _say_memory(devices[:nb], "the window")
         summary = _read_trace(trace_dir, keep_trace) if trace else None
     finally:
         if trace_dir:
@@ -248,7 +263,8 @@ def _end_to_end(cell: spec.Cell, known: dict) -> dict:
 
 
 def _per_layer(cell: spec.Cell, summary: devtrace.TraceSummary, traffic, peak) -> dict:
-    reading = Reading(trace=summary, graph=traffic.spec, peak=peak)
+    reading = Reading(trace=summary, graph=traffic.spec, peak=peak,
+                      programs=traffic.program_texts, walks=traffic.walk)
     out = {}
     for m, reader in cell.per_layer:
         value = reader.read(reading)

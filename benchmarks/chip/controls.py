@@ -7,6 +7,15 @@ configuration broken, which the comparison has to fail.
             one-shot-shuffle: the program's one-shot shuffle
             (shuffle_argsort) in place of the paper's shuffle-exchange,
             which the configuration states pv to be.
+    walks   neighbour-order-rows: the walks run over the program's CSR
+            with each row sorted by neighbour, where the configuration
+            states rows in edge-index order.
+            lossy-walk-exchange: the walkers' exchange sized 10 % under
+            lossless: each bucket of every hop's exchange keeps its first
+            90 % of slots and counts the rest as dropped, as an exchange of
+            that capacity would, where the configuration states that every
+            walker comes back.  (The program itself cannot be sized so at
+            one shard: a walk capacity under the walker count fails.)
 
     python3 benchmarks/chip/controls.py --workload <cell> --seeds 1,2,3 \
         [--control-seeds 1,2] [--controls one-shot-shuffle]
@@ -24,6 +33,7 @@ import contextlib
 import dataclasses
 import json
 import sys
+from functools import partial
 from typing import Iterator, Optional
 
 import spec
@@ -52,8 +62,68 @@ def _one_shot_shuffle(cell):
         yield cell
 
 
+def _exchange_kept(exchange, share: float):
+    """capacity_all_to_all whose buckets keep the first `share` of their
+    slots: a received row's slot is its rank in its sender's bucket."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def lossy(data, dest, *, axis, capacity, **kwargs):
+        ex = exchange(data, dest, axis=axis, capacity=capacity, **kwargs)
+        kept = jnp.arange(capacity) < int(share * capacity)
+        lost = jnp.sum((ex.valid & ~kept).astype(jnp.int32))
+        return ex._replace(valid=ex.valid & kept, dropped=ex.dropped + lax.psum(lost, axis))
+    return lossy
+
+
+@contextlib.contextmanager
+def _lossy_walk_exchange(cell):
+    import jax
+    from repro.data import walks
+
+    # distributed_walks reads the exchange while it is traced: drop the
+    # programs traced before the patch, and those traced under it after
+    jax.clear_caches()
+    try:
+        with _patched(walks, "capacity_all_to_all",
+                      _exchange_kept(walks.capacity_all_to_all, 0.9)):
+            yield cell
+    finally:
+        jax.clear_caches()
+
+
+def _rows_by_neighbour(build_csr):
+    """build_csr, with each shard's rows then sorted by neighbour."""
+    import jax
+    from jax import lax
+
+    import reference
+
+    @partial(jax.jit, static_argnames=("cfg", "mesh", "axis"))
+    def built(cfg, mesh, owned, axis="shards"):
+        csr = build_csr(cfg, mesh, owned, axis)
+        g = reference.GraphSpec(scale=cfg.scale, edge_factor=cfg.edge_factor, a=cfg.a,
+                                b=cfg.b, c=cfg.c, d=cfg.d, nb=cfg.nb)
+        _, rows, cols, _ = reference.csr_pairs(g, csr.offv, csr.adjv, csr.num_edges)
+        rows, cols = rows.reshape(cfg.nb, -1), cols.reshape(cfg.nb, -1)
+        _, cols = jax.vmap(lambda r, c: lax.sort((r, c), num_keys=2))(rows, cols)
+        # slots past a shard's edges hold n, which sorts last in the shard
+        return csr._replace(adjv=cols.reshape(csr.adjv.shape).astype(csr.adjv.dtype))
+    return built
+
+
+@contextlib.contextmanager
+def _neighbour_order_rows(cell):
+    from repro.core import pipeline
+
+    with _patched(pipeline, "build_csr_sorted", _rows_by_neighbour(pipeline.build_csr_sorted)):
+        yield cell
+
+
 CONTROLS = {
     "gen": {"lossy-exchange": _lossy_exchange, "one-shot-shuffle": _one_shot_shuffle},
+    "walks": {"neighbour-order-rows": _neighbour_order_rows,
+              "lossy-walk-exchange": _lossy_walk_exchange},
 }
 
 

@@ -1,5 +1,6 @@
-"""Device time by scope: the `jax.named_scope`s of the phase programs, read
-through the optimised HLO of the programs that a traced window ran.
+"""Device time by scope: the `jax.named_scope`s of the phase and walk
+programs, read through the optimised HLO of the programs that a traced
+window ran.
 
 The program opens a phase scope around each phase program (`shuffle`,
 `edges`, `relabel`, `redistribute`, `csr`), `exchange` inside
@@ -12,9 +13,11 @@ instruction) in the HLO text of its program; a fusion takes its own op_name
 or, lacking one, its root's.
 
 The texts come from compiling every program of a unit again after the
-window (`pipeline.compile_phases`, through the persistent cache, keyed on
-the metadata too), in `--trace 1` runs only, so set-up is untouched.  The graph's seed is a
-constant of the shuffle and edge programs and is not in the trace: the
+window, as the cell's traffic kind names them (`program_texts`: the
+phases of `pipeline.compile_phases` for `gen`, `distributed_walks` for
+`walks`), through the persistent cache, keyed on the metadata too, in
+`--trace 1` runs only, so set-up is untouched.  The graph's and the walk's
+seeds are constants of their programs and are not in the trace: the
 programs are compiled for a stand-in seed, and every operation of the
 window is checked against its program by name and result shape.  A module
 with an operation that its program lacks is left unscoped.  A program that
@@ -33,12 +36,13 @@ from __future__ import annotations
 
 import argparse
 import bisect
+import contextlib
 import copy
 import dataclasses
 import os
 import re
 import sys
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import devtrace
 
@@ -207,53 +211,41 @@ def _matches(program: Dict[str, Instruction], event_name: str) -> bool:
 # ---------------------------------------------------------------------------
 
 
+@contextlib.contextmanager
+def metadata_in_key() -> Iterator[None]:
+    """Compiles keyed on the metadata too.  The persistent cache's key
+    leaves out metadata by default, so a program compiled first by another
+    checkout of the same code less its scopes (the parent of a change, say)
+    would come back with that checkout's op_names."""
+    import jax
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    previous = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, previous)
+
+
 def program_texts(cfg, mesh, shuffle_variant: str) -> Optional[List[str]]:
     """The optimised HLO of every program generate() runs; None where the
-    program cannot compile its phases alone.
-
-    The persistent cache's key leaves out metadata by default, so a
-    program compiled first by another checkout of the same code less its
-    scopes (the parent of a change, say) would come back with that
-    checkout's op_names.  Here the key includes the metadata."""
-    import jax
+    program cannot compile its phases alone."""
     from repro.core import pipeline
 
     compile_phases = getattr(pipeline, "compile_phases", None)
     if compile_phases is None:
         return None
-    flag = "jax_compilation_cache_include_metadata_in_key"
-    previous = getattr(jax.config, flag)
-    jax.config.update(flag, True)
-    try:
+    with metadata_in_key():
         compiled = compile_phases(cfg, mesh, shuffle_variant=shuffle_variant)
         return [c.as_text() for c in compiled.values()]
-    finally:
-        jax.config.update(flag, previous)
 
 
 def program_scopes(reading) -> Optional[Dict[str, Dict[str, Instruction]]]:
-    """Scopes of the programs of the reading's graph, compiled for the
-    configuration of BENCHMARK.json with the graph's sizes and a stand-in
-    seed; None where no configuration has them."""
-    import numpy as np
-    import jax
-    from jax.sharding import Mesh
-
-    import spec
-    import units
-
-    graph = reading.graph
-    for entry in spec.load_benchmark()["configs"]:
-        config = spec.load_json(spec.HERE.parents[1] / entry["file"])
-        if units.graph_spec(config, graph.shuffle) == graph:
-            break
-    else:
-        return None
-    mesh = Mesh(np.asarray(jax.devices()[:graph.nb]), ("shards",))
-    texts = program_texts(units.graph_config(config, STAND_IN_SEED), mesh, graph.shuffle)
-    if texts is None:
-        return None
-    return dict(module_scopes(t) for t in texts)
+    """Scopes of the programs the reading's window ran, as its traffic
+    compiles them for a stand-in seed (`programs`); None where it cannot."""
+    texts = reading.programs()
+    return dict(module_scopes(t) for t in texts) if texts else None
 
 
 def for_reading(reading) -> Optional[ScopedTrace]:
@@ -315,7 +307,7 @@ def main(argv: Sequence[str]) -> int:
     nb = int(cell.config["nb"])
     mesh = Mesh(np.asarray(jax.devices()[:nb]), ("shards",))
     traffic = units.make(cell.config, cell.traffic, args.seed, mesh)
-    texts = program_texts(traffic.cfg, mesh, traffic.variant) or []
+    texts = traffic.program_texts() or []
     planes = devtrace.load_xplane(args.trace)
     summary = devtrace.TraceSummary(planes)
     scoped = ScopedTrace(summary, dict(module_scopes(t) for t in texts))

@@ -3,7 +3,6 @@ optimised HLO, the three readers that sum it, and idle gaps named by the
 program's host spans, on a hand-built trace whose every number is known;
 and the scopes of the real programs, compiled at scale 10."""
 
-import dataclasses
 import sys
 from pathlib import Path
 from types import SimpleNamespace
@@ -224,8 +223,19 @@ def test_a_stand_in_seed_gives_the_same_instructions(tiny_programs):
     assert stand_in == real
 
 
+def _traffic(workload, seed=2**31 + 977, **config):
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    cell = spec.resolve(spec.load_benchmark(), workload)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("shards",))
+    return units.make(dict(cell.config, **config), cell.traffic, seed, mesh)
+
+
 def test_program_scopes_finds_the_configuration_of_the_graph(monkeypatch):
-    graph = units.graph_spec(spec.load_json(HERE / "configs" / "graph500-22.json"), "paper")
+    """The scope map of a gen cell compiles its own configuration's
+    phases, for the stand-in seed, whatever the run's seed."""
     seen = {}
 
     def fake_texts(cfg, mesh, variant):
@@ -233,12 +243,21 @@ def test_program_scopes_finds_the_configuration_of_the_graph(monkeypatch):
         return [HLO]
 
     monkeypatch.setattr(scopes, "program_texts", fake_texts)
-    got = scopes.program_scopes(SimpleNamespace(graph=graph))
+    traffic = _traffic("g500-22.gen-paper")
+    got = scopes.program_scopes(SimpleNamespace(programs=traffic.program_texts))
     assert set(got) == {"jit_redistribute_sorted"}
     assert seen["cfg"].seed == scopes.STAND_IN_SEED and seen["cfg"].scale == 22
     assert seen["cfg"].capacity_factor == 1.0 and seen["variant"] == "paper"
-    other = dataclasses.replace(graph, scale=10)
-    assert scopes.program_scopes(SimpleNamespace(graph=other)) is None
+    # a checkout that cannot compile its phases alone
+    assert scopes.program_scopes(SimpleNamespace(programs=lambda: None)) is None
+
+
+def test_the_gen_cells_scope_map_is_its_phase_programs(tiny_programs):
+    """Asked through its traffic, the gen cell's map is the one built from
+    its configuration's phase programs at the stand-in seed."""
+    traffic = _traffic("g500-22.gen-paper", scale=10)
+    assert scopes.program_scopes(SimpleNamespace(programs=traffic.program_texts)) == \
+        tiny_programs[0]
 
 
 CACHE_TRAP = """

@@ -151,6 +151,23 @@ def test_the_recompute_cell_is_data_alone(tmp_path, benchmark):
     assert result["checks"]["pv_mismatch"]["value"] == 0
 
 
+@pytest.mark.parametrize("walkers,length", [(2048, 80), (4096, 40)])
+def test_a_walk_cell_at_another_size_is_data_alone(tmp_path, benchmark, walkers, length):
+    config = dict(spec.load_json(HERE / "configs" / "node2vec-g500-22.json"),
+                  walk_length=length)
+    name = f"node2vec-g500-22-l{length}"
+    bench_json, root = _data_only_tree(
+        tmp_path, benchmark, name, config, f"walks-{walkers}",
+        {"kind": "walks", "walkers": walkers, "shuffle_variant": "paper"},
+        {"name": f"n2v-22.walks-{walkers}-{length}", "chips": 1, "why": "test"}, "n2v-22.walks")
+    cell = spec.resolve(bench_json, f"n2v-22.walks-{walkers}-{length}", root)
+    assert {m["name"] for m in cell.end_to_end} == {"setup_s", "walk_hops_per_s", "peak_hbm_gib"}
+    assert {m["name"] for m, _ in cell.per_layer} >= {"walks.device_ms", "walks_roofline"}
+    result = bench.run(_tiny(cell), BIG_SEED, 0.3, False, require_tpu=False)
+    assert result["correct"], result["checks"]
+    assert result["checks"]["walk_mismatch"]["value"] == 0
+
+
 FOUR_CHIP = textwrap.dedent("""
     import dataclasses, json, sys
     sys.path.insert(0, sys.argv[1])
