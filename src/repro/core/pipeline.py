@@ -23,7 +23,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..distributed.collectives import flat_mesh
 from .csr import CSRShards, build_csr_scatter, build_csr_sorted
-from .redistribute import OwnedEdges, redistribute, redistribute_sorted
+from .redistribute import OwnedEdges, _default_capacity, redistribute, redistribute_sorted
 from .relabel import relabel_alltoall, relabel_recompute, relabel_ring
 from .rmat import rmat_edge_block
 from .shuffle import distributed_shuffle, shuffle_argsort, shuffle_recompute
@@ -39,6 +39,10 @@ class GraphResult(NamedTuple):
     csr: CSRShards
     dropped_relabel: jnp.ndarray
     dropped_redistribute: jnp.ndarray
+    # the redistribute's most edges from one shard to one owner, and the
+    # records each (shard, owner) pair could carry: lossless iff load <= capacity
+    redistribute_peak_load: jnp.ndarray
+    redistribute_capacity: int
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "axis"))
@@ -147,7 +151,8 @@ def _phases(cfg, mesh, axis, shuffle_variant, call) -> GraphResult:
     with span("csr"):
         csr = call(build_csr, cfg, mesh, owned, axis)
 
-    return GraphResult(pv, new_src, new_dst, owned, csr, dropped_rel, owned.dropped)
+    return GraphResult(pv, new_src, new_dst, owned, csr, dropped_rel, owned.dropped,
+                       owned.peak_load, _default_capacity(cfg, cfg.nb))
 
 
 # ---------------------------------------------------------------------------

@@ -50,12 +50,15 @@ class OwnedEdges(NamedTuple):
     rows are per-sender packets.  Whether the flattened per-shard view is
     globally sorted by src is a property of which redistribute variant
     produced it (§III-B7 => sorted), not a runtime flag — jit traces bools.
+    peak_load is the exchange's most records from one sender to one owner:
+    the exchange was lossless iff it is at most the capacity.
     """
 
     src: jnp.ndarray
     dst: jnp.ndarray
     valid: jnp.ndarray
     dropped: jnp.ndarray
+    peak_load: jnp.ndarray
 
 
 def _default_capacity(cfg: GraphConfig, nb: int) -> int:
@@ -85,16 +88,15 @@ def redistribute(
             owner = src_l // B
         ex = capacity_all_to_all(pair, owner, axis=axis, capacity=cap)
         with jax.named_scope("place"):
-            return ex.data[..., 0], ex.data[..., 1], ex.valid, ex.dropped
+            return ex.data[..., 0], ex.data[..., 1], ex.valid, ex.dropped, ex.peak_load
 
     fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis)),
-        out_specs=(P(axis), P(axis), P(axis), P()),
+        out_specs=(P(axis), P(axis), P(axis), P(), P()),
     )
     with jax.named_scope("redistribute"):
-        s, d, v, drop = fn(src, dst)
-    return OwnedEdges(s, d, v, drop)
+        return OwnedEdges(*fn(src, dst))
 
 
 def merge_received(ex: ExchangeResult, n: int):
@@ -149,13 +151,12 @@ def redistribute_sorted(
             owner = src_s // B
         # sorted by src, so the owners are too: each bucket is one run
         ex = capacity_all_to_all(pair, owner, axis=axis, capacity=cap, dest_sorted=True)
-        return merge_received(ex, cfg.n)
+        return (*merge_received(ex, cfg.n), ex.peak_load)
 
     fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis)),
-        out_specs=(P(axis), P(axis), P(axis), P()),
+        out_specs=(P(axis), P(axis), P(axis), P(), P()),
     )
     with jax.named_scope("redistribute"):
-        s, d, v, drop = fn(src, dst)
-    return OwnedEdges(s, d, v, drop)
+        return OwnedEdges(*fn(src, dst))

@@ -63,6 +63,7 @@ class Buckets(NamedTuple):
     position: jnp.ndarray  # [N] int32  (dest, slot) flattened index each record went to
                            #            (= dest*capacity + slot; capacity*k if dropped)
     dropped: jnp.ndarray   # [] int32   records that exceeded capacity (counted, not lost silently)
+    peak: jnp.ndarray      # [] int32   records bound for the fullest destination, kept or not
 
 
 def bucket_by_destination(data: jnp.ndarray, dest: jnp.ndarray, k: int, capacity: int,
@@ -102,9 +103,11 @@ def bucket_by_destination(data: jnp.ndarray, dest: jnp.ndarray, k: int, capacity
         # times the host memory of the int32 one.
         occupied = jnp.zeros((k * capacity + 1,), jnp.int32).at[slot].set(1, mode="drop")
         dropped = jnp.sum((rank >= capacity) & (dest < k)).astype(jnp.int32)
+        # a group of c records ranks its last one c - 1
+        peak = jnp.max(jnp.where(dest < k, rank + 1, 0), initial=0)
         data_rows = flat[:-1].reshape((k, capacity) + data.shape[1:])
         valid_rows = occupied[:-1].reshape(k, capacity) == 1
-    return Buckets(data=data_rows, valid=valid_rows, position=slot, dropped=dropped)
+    return Buckets(data=data_rows, valid=valid_rows, position=slot, dropped=dropped, peak=peak)
 
 
 def bucket_sorted_runs(data: jnp.ndarray, dest: jnp.ndarray, k: int, capacity: int) -> Buckets:
@@ -133,9 +136,10 @@ def bucket_sorted_runs(data: jnp.ndarray, dest: jnp.ndarray, k: int, capacity: i
         mask = valid_rows.reshape(valid_rows.shape + (1,) * (data.ndim - 1))
         data_rows = jnp.where(mask, rows, jnp.zeros((), data.dtype))
         dropped = jnp.sum(jnp.maximum(counts - capacity, 0)).astype(jnp.int32)
+        peak = jnp.max(counts)
         rank = jnp.arange(n, dtype=jnp.int32) - starts[dest]
         slot = jnp.where(rank < capacity, dest * capacity + rank, k * capacity)
-    return Buckets(data=data_rows, valid=valid_rows, position=slot, dropped=dropped)
+    return Buckets(data=data_rows, valid=valid_rows, position=slot, dropped=dropped, peak=peak)
 
 
 def unbucket(buckets_data: jnp.ndarray, position: jnp.ndarray, fill=0) -> jnp.ndarray:
@@ -162,6 +166,8 @@ class ExchangeResult(NamedTuple):
     valid: jnp.ndarray     # [k, capacity] bool
     position: jnp.ndarray  # [N] local bucketing positions (for the return trip)
     dropped: jnp.ndarray   # [] int32  GLOBAL dropped count (psum'd)
+    peak_load: jnp.ndarray  # [] int32 most records any shard sent to any one shard (pmax'd),
+                            #          the capacity a lossless exchange needed
 
 
 def capacity_all_to_all(
@@ -180,7 +186,9 @@ def capacity_all_to_all(
     valid=False are discarded without consuming capacity.  A caller whose
     `dest` is non-decreasing, because it sorted its records by destination
     itself, says so with dest_sorted=True: the buckets are then its runs
-    (bucket_sorted_runs), with no sort and no scatter.
+    (bucket_sorted_runs), with no sort and no scatter.  Besides the drops,
+    the result counts the load of the fullest (sender, destination) pair,
+    which tells how near the exchange came to dropping.
     """
     k = lax.axis_size(axis)
     with jax.named_scope("exchange"):
@@ -195,7 +203,8 @@ def capacity_all_to_all(
             recv_valid = lax.all_to_all(b.valid.astype(jnp.int32), axis, split_axis=0,
                                         concat_axis=0, tiled=False) == 1
             dropped = lax.psum(b.dropped, axis)
-    return ExchangeResult(recv, recv_valid, b.position, dropped)
+            peak_load = lax.pmax(b.peak, axis)
+    return ExchangeResult(recv, recv_valid, b.position, dropped, peak_load)
 
 
 def return_all_to_all(

@@ -99,9 +99,9 @@ def general(cap):
         pair = jnp.stack([src_s, dst_s], axis=-1)
         ex = capacity_all_to_all(pair, src_s // cfg.bucket_size, axis="shards",
                                  capacity=cap, dest_sorted=False)
-        return merge_received(ex, cfg.n)
+        return (*merge_received(ex, cfg.n), ex.peak_load)
     return jax.jit(jax.shard_map(per_shard, mesh=mesh, in_specs=(P("shards"), P("shards")),
-                                 out_specs=(P("shards"), P("shards"), P("shards"), P())))
+                                 out_specs=(P("shards"), P("shards"), P("shards"), P(), P())))
 
 # the phase's own capacity (lossless), then one that drops
 for cap in (_default_capacity(cfg, nb), cfg.edges_per_shard // nb // 2):
@@ -116,6 +116,77 @@ assert int(got.dropped) > 0
 print("OKRUNS")
 """)
     assert "OKRUNS" in out
+
+
+@pytest.mark.parametrize("nb", [1, 4])
+def test_the_exchange_counts_its_fullest_pair(nb):
+    """capacity_all_to_all's peak_load is the most records one shard sent to
+    one destination, counted in numpy, for both bucketings, whether the
+    capacity holds them all or drops some."""
+    out = run_py(f"""
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+from repro.distributed.collectives import capacity_all_to_all, flat_mesh
+
+nb, N = {nb}, 512
+mesh = flat_mesh(nb)
+rng = np.random.default_rng(7)
+# skewed: each shard sends most of its records to the next one
+dest = np.where(rng.random((nb, N)) < 0.6, (np.arange(nb)[:, None] + 1) % nb,
+                rng.integers(0, nb, (nb, N))).astype(np.int32)
+data = rng.integers(0, 1 << 30, (nb, N)).astype(np.int32)
+pairs = np.array([[(dest[s] == d).sum() for d in range(nb)] for s in range(nb)])
+want = int(pairs.max())
+
+def exchange(cap, dest_sorted):
+    def per_shard(x, d):
+        if dest_sorted:
+            order = jnp.argsort(d, stable=True)
+            x, d = x[order], d[order]
+        ex = capacity_all_to_all(x, d, axis="shards", capacity=cap, dest_sorted=dest_sorted)
+        return ex.dropped, ex.peak_load
+    return jax.jit(jax.shard_map(per_shard, mesh=mesh, in_specs=(P("shards"), P("shards")),
+                                 out_specs=(P(), P())))
+
+for dest_sorted in (False, True):
+    for cap in (want + 8, want, want - 1, N // (2 * nb)):
+        dropped, peak = exchange(cap, dest_sorted)(data.reshape(-1), dest.reshape(-1))
+        lost = int(np.maximum(pairs - cap, 0).sum())
+        assert int(peak) == want, (dest_sorted, cap, int(peak), want)
+        assert int(dropped) == lost and (lost > 0) == (want > cap), (dest_sorted, cap, lost)
+print("OKPEAK", want)
+""")
+    assert "OKPEAK" in out
+
+
+def test_the_graph_reports_its_redistribute_load():
+    """GraphResult.redistribute_peak_load is the most relabeled edges one
+    shard sends to one owner, at most the capacity wherever nothing was
+    dropped, and above it where the exchange drops."""
+    out = run_py("""
+import numpy as np
+from repro.core.types import GraphConfig
+from repro.core.pipeline import generate
+from repro.core.redistribute import _default_capacity, redistribute_sorted
+from repro.distributed.collectives import flat_mesh
+
+for nb, factor in ((1, 1.0), (4, 1.1), (4, 4.0)):
+    cfg = GraphConfig(scale=10, nb=nb, capacity_factor=factor)
+    mesh = flat_mesh(nb)
+    res = generate(cfg, mesh)
+    load, cap = int(res.redistribute_peak_load), res.redistribute_capacity
+    assert cap == _default_capacity(cfg, nb)
+    owner = np.asarray(res.src).reshape(nb, -1) // cfg.bucket_size
+    want = max(int((owner[s] == d).sum()) for s in range(nb) for d in range(nb))
+    assert load == want, (nb, factor, load, want)
+    if int(res.dropped_redistribute) == 0:
+        assert load <= cap, (nb, factor, load, cap)
+    print(nb, factor, load, cap)
+small = redistribute_sorted(cfg, mesh, res.src, res.dst, capacity=want // 2)
+assert int(small.dropped) > 0 and int(small.peak_load) == want
+print("OKLOAD")
+""")
+    assert "OKLOAD" in out
 
 
 def test_distributed_walks_match_host_oracle():

@@ -84,6 +84,31 @@ def test_sorted_runs_bucket_as_the_general_bucketing(n, k, cap_frac, seed, width
 
 
 @SETTINGS
+@given(
+    n=st.integers(1, 300),
+    k=st.integers(1, 8),
+    cap_frac=st.floats(0.1, 3.0),
+    seed=st.integers(0, 2**31 - 1),
+    masked=st.booleans(),
+)
+def test_bucket_peak_is_the_fullest_destination(n, k, cap_frac, seed, masked):
+    """Both bucketings count the records bound for the fullest destination,
+    kept or dropped; rows masked invalid count for none."""
+    rng = np.random.default_rng(seed)
+    dest = np.sort(rng.integers(0, k, n)).astype(np.int32)
+    data = rng.integers(0, 1 << 30, n).astype(np.int32)
+    valid = rng.random(n) < 0.7 if masked else np.ones(n, bool)
+    capacity = max(1, int(n * cap_frac / k))
+    want = max(int(((dest == j) & valid).sum()) for j in range(k))
+    b = bucket_by_destination(jnp.asarray(data), jnp.asarray(dest), k, capacity,
+                              valid=jnp.asarray(valid) if masked else None)
+    assert int(b.peak) == want
+    assert (int(b.dropped) > 0) == (want > capacity)
+    if not masked:
+        assert int(bucket_sorted_runs(jnp.asarray(data), jnp.asarray(dest), k, capacity).peak) == want
+
+
+@SETTINGS
 @given(n=st.integers(0, 200), m=st.integers(0, 200), seed=st.integers(0, 2**31 - 1))
 def test_merge_two_sorted(n, m, seed):
     rng = np.random.default_rng(seed)

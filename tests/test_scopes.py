@@ -201,6 +201,30 @@ def test_only_the_sorted_redistribute_buckets_by_runs(hlo):
         assert "scatter" in {op for op, _ in ops}, program
 
 
+def kinds_of(text: str):
+    """{kind} of every instruction of a program, fused ones included."""
+    return {k for _, ins, name in instructions(text, lambda ins, comp: ins.op in BOOKKEEPING)
+            for part in (name or "").split(";") for k in part.split("/")[:-1]
+            if k in KIND_SCOPES}
+
+
+def test_the_paper_programs_cross_chips_under_collective_and_merge(hlo, request):
+    """What the four-chip cell's collective and merge readers sum: at nb = 4
+    the shuffle rounds, the ring relabel and the redistribute each run a
+    collective (the exchange's drop psum and peak-load pmax among them), and
+    the redistribute merges the runs it received; at nb = 1 only the merge's
+    bookkeeping of its one run is left."""
+    merged = "merge" in kinds_of(hlo["redistribute_sorted"])
+    crossing = {p for p in PROGRAMS["paper"] if "collective" in kinds_of(hlo[p])}
+    if request.node.callspec.params["hlo"] == 4:
+        assert merged and crossing == {"distributed_shuffle", "relabel_ring",
+                                       "redistribute_sorted"}, crossing
+        assert re.search(r"all-reduce\(.*op_name=\"[^\"]*/exchange/collective/pmax",
+                         hlo["redistribute_sorted"]), "the peak load's pmax"
+    else:
+        assert merged
+
+
 def test_the_vocabulary_is_disjoint():
     vocab = list(PHASE_SCOPES) + [EXCHANGE_SCOPE] + list(KIND_SCOPES)
     assert len(set(vocab)) == len(vocab)
