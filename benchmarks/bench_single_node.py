@@ -28,7 +28,7 @@ def run(scales=(10, 12, 14, 16), base=16):
         pv = distributed_shuffle(cfg, mesh)
         t_gen = time_fn(lambda: generate_edges(cfg, mesh))
         src, dst = generate_edges(cfg, mesh)
-        t_rel = time_fn(lambda: relabel_ring(cfg, mesh, src, dst, pv))
+        t_rel = time_fn(lambda: relabel_ring(cfg, mesh, jnp.copy(src), jnp.copy(dst), pv))
         nsrc, ndst = relabel_ring(cfg, mesh, src, dst, pv)
         t_red_s = time_fn(lambda: redistribute_sorted(cfg, mesh, nsrc, ndst))
         owned_s = redistribute_sorted(cfg, mesh, nsrc, ndst)

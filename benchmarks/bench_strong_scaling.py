@@ -22,6 +22,7 @@ from .common import print_table, save_json
 _CHILD = r"""
 import os, sys, json, time
 import jax
+import jax.numpy as jnp
 from repro.core.types import GraphConfig
 from repro.core.pipeline import generate_edges
 from repro.core.shuffle import distributed_shuffle
@@ -49,7 +50,7 @@ res["shuffle"] = t(lambda: distributed_shuffle(cfg, mesh))
 pv = distributed_shuffle(cfg, mesh)
 res["edge_gen"] = t(lambda: generate_edges(cfg, mesh))
 src, dst = generate_edges(cfg, mesh)
-res["relabel"] = t(lambda: relabel_ring(cfg, mesh, src, dst, pv))
+res["relabel"] = t(lambda: relabel_ring(cfg, mesh, jnp.copy(src), jnp.copy(dst), pv))
 ns, nd = relabel_ring(cfg, mesh, src, dst, pv)
 res["redistribute"] = t(lambda: redistribute_sorted(cfg, mesh, ns, nd))
 owned = redistribute_sorted(cfg, mesh, ns, nd)

@@ -22,6 +22,7 @@ from .common import print_table, save_json
 _CHILD = r"""
 import os, sys, json, time
 import jax
+import jax.numpy as jnp
 from repro.core.types import GraphConfig
 from repro.core.pipeline import generate_edges
 from repro.core.shuffle import distributed_shuffle
@@ -45,7 +46,7 @@ def t(fn):
 pv = distributed_shuffle(cfg, mesh)
 src, dst = generate_edges(cfg, mesh)
 res = {}
-res["relabel"] = t(lambda: relabel_ring(cfg, mesh, src, dst, pv))
+res["relabel"] = t(lambda: relabel_ring(cfg, mesh, jnp.copy(src), jnp.copy(dst), pv))
 ns, nd = relabel_ring(cfg, mesh, src, dst, pv)
 res["redistribute"] = t(lambda: redistribute_sorted(cfg, mesh, ns, nd))
 res["platform"] = jax.devices()[0].platform

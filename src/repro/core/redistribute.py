@@ -15,8 +15,9 @@ Two variants, mirroring the paper:
   redistribute            unordered (paper's implemented version, §III-B5)
   redistribute_sorted     §III-B7: senders pre-sort by new source, so each
                           packet is one contiguous run of sorted records;
-                          the receiver k-way-merges the nb sorted runs =>
-                          its edges arrive globally sorted by source and the
+                          the receiver merges the nb sorted runs by one
+                          stable sort of their concatenation => its edges
+                          arrive globally sorted by source and the
                           CSR build degenerates to the trivial Alg. 1.
                           (The paper proposes but does NOT implement this
                           variant; we implement both and benchmark the gap.)
@@ -100,19 +101,17 @@ def redistribute(
 
 
 def merge_received(ex: ExchangeResult, n: int):
-    """Receive side of redistribute_sorted: k-way merge of the nb sorted
-    packets that arrived, empty slots keyed past every vertex (`n`) so they
-    sink to the end.  Returns (src, dst, valid, dropped) in the [nb, cap]
-    layout, whose flattened view is sorted by src."""
+    """Receive side of redistribute_sorted: merge of the nb sorted packets
+    that arrived, empty slots keyed past every vertex (`n`) so they sink to
+    the end.  Returns (src, dst, valid, dropped) in the [nb, cap] layout,
+    whose flattened view is sorted by src."""
     nb, cap = ex.valid.shape
     with jax.named_scope("merge"):
-        rs, rd, rv = ex.data[..., 0], ex.data[..., 1], ex.valid
-        keys = jnp.where(rv, rs, jnp.asarray(n, rs.dtype))
-        payload = jnp.stack([rd, rv.astype(rd.dtype)], axis=-1)
-        mkeys, mpay = merge_sorted_runs(keys, payload)
-        mvalid = mpay[..., 1].astype(jnp.bool_)
+        rs, rd = ex.data[..., 0], ex.data[..., 1]
+        keys = jnp.where(ex.valid, rs, jnp.asarray(n, rs.dtype))
+        mkeys, mdst = merge_sorted_runs(keys, rd)
+        mvalid = mkeys < n                                 # every valid source is below n
         msrc = jnp.where(mvalid, mkeys, 0)
-        mdst = mpay[..., 0]
         return (
             msrc.reshape(nb, cap),
             mdst.reshape(nb, cap),

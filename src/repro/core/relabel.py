@@ -89,7 +89,7 @@ def _relabel_field_ring(field: jnp.ndarray, pv_local: jnp.ndarray, *, bid, nb: i
         return jnp.zeros_like(field).at[sort_idx].set(out_sorted)
 
 
-@partial(jax.jit, static_argnames=("cfg", "mesh", "axis"))
+@partial(jax.jit, static_argnames=("cfg", "mesh", "axis"), donate_argnames=("src", "dst"))
 def relabel_ring(
     cfg: GraphConfig,
     mesh: Mesh,
@@ -99,7 +99,13 @@ def relabel_ring(
     axis: str = "shards",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Paper-faithful relabel: dst pass then src pass (paper relabels the
-    destination field first, then the source field — Alg. 7 runs twice)."""
+    destination field first, then the source field — Alg. 7 runs twice).
+
+    Consumes the raw edges: `src` and `dst` are donated, and the relabelled
+    fields are written into their buffers.  The host dispatches every phase
+    before the first has run, so edges still held would sit beside the
+    redistribute's and the CSR's outputs.  A caller that needs the raw edges
+    again passes copies."""
     nb = mesh.shape[axis]
     B = cfg.bucket_size
 

@@ -251,54 +251,16 @@ def ring_shift(x: jnp.ndarray, axis: str, shift: int = 1) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def merge_two_sorted(a: jnp.ndarray, b: jnp.ndarray, a_payload=None, b_payload=None):
-    """Merge two sorted arrays in O(n) sequential-access style using
-    searchsorted ranks (no comparison sort).
+def merge_sorted_runs(keys: jnp.ndarray, payload: jnp.ndarray):
+    """Merge k sorted runs [k, run_len], `payload` (the keys' shape) carried
+    along, into one sorted run of k * run_len.
 
-    Returns merged keys (and merged payloads if given).  This is the TPU
-    analogue of the paper's streaming sorted-merge: every element's final
-    position is computed by a binary search + add, all memory access patterns
-    are sequential scans or monotone gathers.
+    Stable: equal keys keep run order, then position within the run.  For
+    k > 1 this is one stable sort of the runs concatenated in run order; one
+    run is returned as it is, with no sort.  On a TPU the sort is far
+    cheaper than a tree of pairwise merges by binary-search ranks, whose
+    O(m log k) steps run as searches in HBM (PERF.md has both readings).
     """
-    na, nb_ = a.shape[0], b.shape[0]
-    pos_a = jnp.arange(na, dtype=jnp.int32) + jnp.searchsorted(b, a, side="left").astype(jnp.int32)
-    pos_b = jnp.arange(nb_, dtype=jnp.int32) + jnp.searchsorted(a, b, side="right").astype(jnp.int32)
-    # pos_a and pos_b are each strictly increasing (a sorted index plus a
-    # monotone rank).  Saying so lets the TPU compiler emit a plain scatter,
-    # which compiles faster and needs less temporary memory.
-    put = dict(indices_are_sorted=True, unique_indices=True)
-    out = jnp.zeros((na + nb_,), a.dtype)
-    out = out.at[pos_a].set(a, **put).at[pos_b].set(b, **put)
-    if a_payload is None:
-        return out
-    pay = jnp.zeros((na + nb_,) + a_payload.shape[1:], a_payload.dtype)
-    pay = pay.at[pos_a].set(a_payload, **put).at[pos_b].set(b_payload, **put)
-    return out, pay
-
-
-def merge_sorted_runs(keys: jnp.ndarray, payload: Optional[jnp.ndarray] = None):
-    """K-way merge of k sorted runs [k, run_len] via log2(k) pairwise rounds.
-
-    O(m log k) work with sequential access — cheaper than re-sorting
-    (O(m log m)) and faithful to the paper's sorted-merge redistribute.
-    k must be a power of two (mesh axis sizes are).
-
-    Runs stay separate arrays between rounds: stacking a round's outputs
-    and slicing the next round's inputs back out of the stack makes the
-    TPU compile of a 4-way merge of 2^24-long runs take minutes.
-    """
-    k = keys.shape[0]
-    assert (k & (k - 1)) == 0, f"k={k} must be a power of two"
-    runs = [keys[i] for i in range(k)]
-    pays = [None if payload is None else payload[i] for i in range(k)]
-    while len(runs) > 1:
-        if payload is None:
-            runs = [merge_two_sorted(runs[i], runs[i + 1])
-                    for i in range(0, len(runs), 2)]
-        else:
-            merged = [merge_two_sorted(runs[i], runs[i + 1], pays[i], pays[i + 1])
-                      for i in range(0, len(runs), 2)]
-            runs, pays = [m[0] for m in merged], [m[1] for m in merged]
-    if payload is None:
-        return runs[0]
-    return runs[0], pays[0]
+    if keys.shape[0] == 1:
+        return keys[0], payload[0]
+    return lax.sort((keys.reshape(-1), payload.reshape(-1)), num_keys=1, is_stable=True)

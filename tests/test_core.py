@@ -83,6 +83,24 @@ def test_relabel_variants_agree():
         V.edge_multiset(r_a2a.src, r_a2a.dst))
 
 
+def test_the_ring_relabel_writes_into_the_raw_edges():
+    """relabel_ring takes the raw edges' buffers for its output (they are
+    donated), and maps each endpoint v to pv[v] in place."""
+    from repro.core.pipeline import generate_edges
+    from repro.core.relabel import relabel_ring
+    from repro.core.shuffle import distributed_shuffle
+    from repro.distributed.collectives import flat_mesh
+
+    mesh = flat_mesh(1)
+    pv = distributed_shuffle(CFG, mesh)
+    src, dst = generate_edges(CFG, mesh)
+    want = [np.asarray(pv)[np.asarray(e)] for e in (src, dst)]
+    got = relabel_ring(CFG, mesh, src, dst, pv)
+    assert src.is_deleted() and dst.is_deleted()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+
+
 def test_baseline_hash_kernel():
     """The memory-resident Graph500 baseline produces a valid CSR with the
     same edge count and de-biased endpoints."""

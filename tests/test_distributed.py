@@ -118,6 +118,57 @@ print("OKRUNS")
     assert "OKRUNS" in out
 
 
+def test_the_four_way_merge_is_a_stable_sort_of_the_received_runs():
+    """At nb = 4, merge_received's four arrays equal, bit for bit, a NumPy
+    stable sort of the runs each shard received, empty slots keyed n: at the
+    phase's capacity and at one that drops, so empty slots are covered."""
+    out = run_py("""
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+from repro.core.types import GraphConfig
+from repro.core.pipeline import generate
+from repro.core.redistribute import _default_capacity, merge_received
+from repro.distributed.collectives import capacity_all_to_all, flat_mesh
+
+nb = 4
+cfg = GraphConfig(scale=10, nb=nb, capacity_factor=4.0)
+mesh = flat_mesh(nb)
+res = generate(cfg, mesh)
+
+def received_and_merged(cap):
+    def per_shard(src_l, dst_l):
+        order = jnp.argsort(src_l)
+        src_s, dst_s = src_l[order], dst_l[order]
+        pair = jnp.stack([src_s, dst_s], axis=-1)
+        ex = capacity_all_to_all(pair, src_s // cfg.bucket_size, axis="shards",
+                                 capacity=cap, dest_sorted=True)
+        return (ex.data, ex.valid, *merge_received(ex, cfg.n))
+    spec = P("shards")
+    return jax.jit(jax.shard_map(per_shard, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=(spec, spec, spec, spec, spec, P())))
+
+for cap in (_default_capacity(cfg, nb), cfg.edges_per_shard // nb // 2):
+    data, valid, msrc, mdst, mvalid, dropped = received_and_merged(cap)(res.src, res.dst)
+    data, valid = np.asarray(data).reshape(nb, -1, 2), np.asarray(valid).reshape(nb, -1)
+    got = [np.asarray(a).reshape(nb, -1) for a in (msrc, mdst, mvalid)]
+    for s in range(nb):
+        keys = np.where(valid[s], data[s, :, 0], cfg.n)
+        order = np.argsort(keys, kind="stable")
+        want = (np.where(keys < cfg.n, keys, 0)[order], data[s, :, 1][order], valid[s][order])
+        for g, w in zip(got, want):
+            assert g[s].dtype == w.dtype, (g.dtype, w.dtype)
+            np.testing.assert_array_equal(g[s], w)
+    owner = np.asarray(res.src).reshape(nb, -1) // cfg.bucket_size
+    lost = sum(max(int((owner[s] == d).sum()) - cap, 0) for s in range(nb) for d in range(nb))
+    assert int(dropped) == lost, (cap, int(dropped), lost)
+    print(cap, int(dropped), int((~valid).sum()))
+    assert int(dropped) > 0 or not valid.all()  # lossless: empty slots to check
+assert int(dropped) > 0
+print("OKMERGE")
+""")
+    assert "OKMERGE" in out
+
+
 @pytest.mark.parametrize("nb", [1, 4])
 def test_the_exchange_counts_its_fullest_pair(nb):
     """capacity_all_to_all's peak_load is the most records one shard sent to

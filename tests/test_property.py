@@ -1,5 +1,6 @@
 """Hypothesis property tests on the system's invariants."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,7 +10,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core.types import GraphConfig, owner_of, quadrant_thresholds
 from repro.distributed.collectives import (
-    bucket_by_destination, bucket_sorted_runs, merge_sorted_runs, merge_two_sorted, unbucket)
+    bucket_by_destination, bucket_sorted_runs, merge_sorted_runs, unbucket)
 from repro.kernels import ref
 from repro.serve.sampling import SamplingParams, sample
 from repro.train.fault import StragglerPolicy
@@ -108,14 +109,33 @@ def test_bucket_peak_is_the_fullest_destination(n, k, cap_frac, seed, masked):
         assert int(bucket_sorted_runs(jnp.asarray(data), jnp.asarray(dest), k, capacity).peak) == want
 
 
+@pytest.mark.parametrize("k", [1, 2, 4, 8])
 @SETTINGS
-@given(n=st.integers(0, 200), m=st.integers(0, 200), seed=st.integers(0, 2**31 - 1))
-def test_merge_two_sorted(n, m, seed):
+@given(run=st.integers(1, 64), hi=st.integers(1, 8), seed=st.integers(0, 2**31 - 1))
+def test_merge_sorted_runs_keeps_run_order_on_ties(k, run, hi, seed):
+    """Keys from a small range tie across runs; the payload names each
+    record's run and position, so the merged payload shows the tie order:
+    that of NumPy's stable sort of the runs concatenated in run order.  The
+    payload falls along that order, so a sort that also keyed on it would
+    reverse the ties."""
     rng = np.random.default_rng(seed)
-    a = np.sort(rng.integers(0, 1000, n)).astype(np.int32)
-    b = np.sort(rng.integers(0, 1000, m)).astype(np.int32)
-    out = np.asarray(merge_two_sorted(jnp.asarray(a), jnp.asarray(b)))
-    np.testing.assert_array_equal(out, np.sort(np.concatenate([a, b]), kind="stable"))
+    keys = np.sort(rng.integers(0, hi, (k, run)), axis=1).astype(np.int32)
+    payload = -(np.arange(k)[:, None] * 10**6 + np.arange(run)).astype(np.int32)
+    mkeys, mpay = merge_sorted_runs(jnp.asarray(keys), jnp.asarray(payload))
+    order = np.argsort(keys.reshape(-1), kind="stable")
+    np.testing.assert_array_equal(np.asarray(mkeys), keys.reshape(-1)[order])
+    np.testing.assert_array_equal(np.asarray(mpay), payload.reshape(-1)[order])
+
+
+def test_one_run_is_merged_without_a_sort():
+    """One run (one shard) is returned as it is; more are merged by one sort."""
+    def sorts(k):
+        keys = jnp.zeros((k, 16), jnp.int32)
+        jaxpr = jax.make_jaxpr(merge_sorted_runs)(keys, keys)
+        return [e for e in jaxpr.eqns if e.primitive.name == "sort"]
+
+    assert sorts(1) == []
+    assert len(sorts(4)) == 1
 
 
 @SETTINGS
@@ -124,8 +144,8 @@ def test_merge_sorted_runs(logk, run, seed):
     k = 1 << logk
     rng = np.random.default_rng(seed)
     runs = np.sort(rng.integers(0, 10_000, (k, run)), axis=1).astype(np.int32)
-    out = np.asarray(merge_sorted_runs(jnp.asarray(runs)))
-    np.testing.assert_array_equal(out, np.sort(runs.reshape(-1)))
+    out, _ = merge_sorted_runs(jnp.asarray(runs), jnp.asarray(runs))
+    np.testing.assert_array_equal(np.asarray(out), np.sort(runs.reshape(-1)))
 
 
 def test_merge_sorted_runs_payload():

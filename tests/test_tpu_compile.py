@@ -145,3 +145,26 @@ def test_sorted_redistribute_buckets_without_a_scatter_at_cell_scale(mesh1):
     scatters = [line for line in compiled.as_text().splitlines()
                 if re.search(r" scatter\(", line) and "/exchange/" in line]
     assert scatters == []
+
+
+def test_the_four_way_merge_is_one_sort_at_cell_scale(topo):
+    """redistribute_sorted at the four-chip cell's shape (Graph500 scale 22,
+    nb=4, capacity_factor 1.1): the received runs are merged by one sort
+    under `redistribute/merge`, with no scatter there, and a chip holds no
+    more than with the tree of pairwise merges: argument + output + temp
+    690,771,968 B there, read from the same compile of that program."""
+    import re
+
+    from repro.core.redistribute import redistribute_sorted
+
+    mesh4 = Mesh(np.asarray(topo.devices[:4]), ("shards",))
+    cfg = GraphConfig(scale=22, nb=4, capacity_factor=1.1)
+    edges = jax.ShapeDtypeStruct((cfg.m,), jnp.int32, sharding=NamedSharding(mesh4, P("shards")))
+    compiled = redistribute_sorted.lower(cfg, mesh4, edges, edges).compile()
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes) <= 690_771_968
+    under_merge = [line for line in compiled.as_text().splitlines()
+                   if '/redistribute/shard_map/merge/' in line]
+    assert len([line for line in under_merge if re.search(r" sort\(", line)]) == 1
+    assert [line for line in under_merge if re.search(r" scatter\(", line)] == []
